@@ -269,7 +269,6 @@ def pack_weights_sharded(w, cfg, mesh, *, n_axis: str = "model"):
     an indivisible N or a mesh without ``n_axis``) falls back to the
     global :func:`~repro.core.quantized.pack_weights`.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from . import quantized as Q  # local import: packed stays dependency-light
@@ -287,7 +286,7 @@ def pack_weights_sharded(w, cfg, mesh, *, n_axis: str = "model"):
         pw = Q.pack_weights(wl, cfg)
         return pw.ka, pw.kscale, pw.tscale, pw.bits
 
-    ka, kscale, tscale, bits = shard_map(
+    ka, kscale, tscale, bits = jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(P(*lead, None, n_axis),),

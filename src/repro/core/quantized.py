@@ -100,8 +100,10 @@ def grouped_int_matmul(qx: dict, qw: dict) -> jax.Array:
     """
     ax = qx["a"].astype(jnp.float32)
     aw = qw["a"].astype(jnp.float32)
-    # exact: products < 2**18, 64-sums < 2**24 -> f32 integer-exact
-    partial_ = jnp.einsum("mgi,ngi->mng", ax, aw)
+    # exact: products < 2**18, 64-sums < 2**24 -> f32 integer-exact, at
+    # full precision (a TPU's default would round |a_x| < 2**11 to bfloat16)
+    partial_ = jnp.einsum("mgi,ngi->mng", ax, aw,
+                          precision=jax.lax.Precision.HIGHEST)
     scaled = partial_ * (qx["scale"][:, None, :] * qw["scale"][None, :, :])
     y = jnp.sum(scaled, axis=-1)
     tx = qx["tscale"].reshape(-1, 1) if jnp.ndim(qx["tscale"]) else qx["tscale"]

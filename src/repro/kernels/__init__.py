@@ -7,7 +7,8 @@
   flash_attention — blockwise online-softmax attention for serving
 
 Each kernel: <name>.py (pl.pallas_call + BlockSpec) with its jnp oracle in
-ref.py and the jit'd public wrapper in ops.py.  Validated in interpret mode
-on CPU; compiled on TPU (REPRO_PALLAS_INTERPRET=0).
+ref.py and the jit'd public wrapper in ops.py.  backend.py decides how they
+run: interpreted on the CPU backend (where the tests validate them),
+compiled by Mosaic everywhere else.
 """
 from . import ops, ref  # noqa: F401

@@ -18,8 +18,9 @@ scales are all powers of two, so ``sx/ts_x`` and ``sw/ts_w`` are exact f32
 values, and multiplying the aligned integer mantissas (|a_x| < 2**11,
 |a_w| < 2**7, exact in f32) by them only adjusts exponents — no mantissa
 bit is ever rounded before the MXU dot.  The kernel is bit-exact vs
-``core.quantized.dsbp_matmul_ref`` under the default RNE path at the
-default full-K reduction block (tests/test_fused.py).
+``core.quantized.dsbp_matmul_ref`` under the default RNE path whenever the
+reduction fits one block (tests/test_fused.py); on the MXU the dot runs at
+``Precision.HIGHEST``, so the integer products stay exact there too.
 
 The weight operands are consumed in the container's stored kernel layout
 (``PackedDSBPWeight.ka (K', N)`` int8 / ``.kscale (ng, N)``), so the
@@ -36,7 +37,8 @@ from jax.experimental import pallas as pl
 
 from repro.core.dsbp import DSBPConfig
 
-from .fp8_quant_align import quant_align_tile
+from . import backend
+from .fp8_quant_align import pick_bk, quant_align_tile
 
 GROUP = 64
 
@@ -44,7 +46,7 @@ __all__ = ["dsbp_fused_kernel_call", "dsbp_fused_sharded_call", "GROUP"]
 
 
 def _kernel(x_ref, ts_ref, aw_ref, sw_ref, tw_ref, o_ref, *,
-            cfg: DSBPConfig, groups_per_blk: int):
+            cfg: DSBPConfig):
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
@@ -52,19 +54,18 @@ def _kernel(x_ref, ts_ref, aw_ref, sw_ref, tw_ref, o_ref, *,
         o_ref[...] = jnp.zeros_like(o_ref)
 
     ts = ts_ref[0, 0]  # per-tensor input scale (power of two)
-    # ---- on-the-fly input path, entirely in VMEM ----
+    # ---- on-the-fly input path, entirely in VMEM; scales come per lane ----
     a, s, _bits = quant_align_tile(x_ref[...].astype(jnp.float32) * ts, cfg)
-    bm, bk = a.shape
-    bn = aw_ref.shape[1]
-    gpb = groups_per_blk
     # ---- fold the pow2 tensor scales into the pow2 group scales (exact)
     # and run the folded MXU dot (dsbp_matmul._kernel_folded) ----
-    ae = (a.reshape(bm, gpb, GROUP) * (s / ts)[:, :, None]).reshape(bm, bk)
+    ae = a * (s / ts)
+    gpb, _, bn = sw_ref.shape  # (groups, 1, bn): one scale row per group
     we = (
         aw_ref[...].astype(jnp.float32).reshape(gpb, GROUP, bn)
-        * (sw_ref[...] / tw_ref[...])[:, None, :]
-    ).reshape(bk, bn)
-    o_ref[...] += jnp.dot(ae, we, preferred_element_type=jnp.float32)
+        * (sw_ref[...] / tw_ref[...])
+    ).reshape(gpb * GROUP, bn)
+    o_ref[...] += jnp.dot(ae, we, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(
@@ -81,7 +82,7 @@ def dsbp_fused_kernel_call(
     bm: int = 128,
     bn: int = 256,
     bk: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """One-pass DSBP GEMM over a (M, N, K) grid.
 
@@ -95,19 +96,23 @@ def dsbp_fused_kernel_call(
     via in-kernel folding — no post-GEMM elementwise pass.
 
     M is ragged-friendly (auto-padded to the row block and sliced back).
-    ``bk=None`` (default) puts the whole reduction in one grid step — the
-    bit-exact configuration: cross-group accumulation then happens in the
-    very same reduction shape as ``dsbp_matmul_ref``.  Explicit ``bk``
-    tiles K for VMEM-constrained shapes at the cost of a different (still
-    exact-integer, f32-accumulated) summation order.
+    ``bk=None`` (default) picks the reduction block with :func:`pick_bk`:
+    the whole reduction in one grid step while the tiles fit VMEM — the
+    bit-exact configuration, cross-group accumulation then happens in the
+    very same reduction shape as ``dsbp_matmul_ref`` — and 128-aligned K
+    tiles beyond that (yi-9b's K = 4096 and 11008 at serving row blocks).
+    K tiles keep every per-tile dot an exact integer dot; only the f32
+    accumulation across tiles is ordered differently from the reference.
     """
+    if interpret is None:
+        interpret = backend.interpret_default()
     m, k = x.shape
     n = aw.shape[1]
     ng = k // GROUP
     assert k % GROUP == 0 and aw.shape[0] == k, (x.shape, aw.shape)
     assert sw.shape == (ng, n) and tw.shape == (1, n), (sw.shape, tw.shape)
-    bk = k if bk is None else min(bk, k)
     bm, bn = min(bm, m), min(bn, n)
+    bk = pick_bk(k, bm, bn) if bk is None else min(bk, k)
     assert n % bn == 0 and k % bk == 0 and bk % GROUP == 0
     pad_m = (-m) % bm
     if pad_m:  # zero rows quantize to a=0 -> zero output rows, sliced away
@@ -116,19 +121,21 @@ def dsbp_fused_kernel_call(
     ts = jnp.asarray(ts, jnp.float32).reshape(1, 1)
     gpb = bk // GROUP
     y = pl.pallas_call(
-        functools.partial(_kernel, cfg=cfg, groups_per_blk=gpb),
+        functools.partial(_kernel, cfg=cfg),
         grid=(mp // bm, n // bn, k // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((gpb, bn), lambda i, j, kk: (kk, j)),
+            # (ng, 1, N): a (gpb, 1, bn) block is legal for any gpb, where
+            # a (gpb, bn) one needs gpb % 8 == 0 (K = 11008 has 172 groups)
+            pl.BlockSpec((gpb, 1, bn), lambda i, j, kk: (kk, 0, j)),
             pl.BlockSpec((1, bn), lambda i, j, kk: (0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         interpret=interpret,
-    )(x, ts, aw, sw, tw)
+    )(x, ts, aw, sw.reshape(ng, 1, n), tw)
     return y[:m] if pad_m else y
 
 
@@ -147,7 +154,7 @@ def dsbp_fused_sharded_call(
     bm: int = 128,
     bn: int = 256,
     bk: int | None = None,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """The one-pass DSBP GEMM under ``shard_map``, collective folded in
     (DESIGN.md §11).
@@ -180,7 +187,6 @@ def dsbp_fused_sharded_call(
     dsbp_matmul_fused_sharded`` checks and falls back to replication per
     axis, mirroring the sharding-rule behavior (parallel/sharding.py).
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     m, k = x.shape
@@ -214,7 +220,7 @@ def dsbp_fused_sharded_call(
             y = jax.lax.psum(y, k_axis)  # fold the contraction partials
         return y
 
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(
@@ -225,5 +231,5 @@ def dsbp_fused_sharded_call(
             P(None, n_axis),         # tscale
         ),
         out_specs=P(batch_axis, n_axis),
-        check_rep=False,  # jit-wrapped pallas_call defeats rep inference
+        check_vma=False,  # jit-wrapped pallas_call defeats vma inference
     )(x, ts, aw, sw, tw)

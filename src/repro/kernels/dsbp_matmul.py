@@ -12,9 +12,10 @@ Integer mantissas (|ax| < 2**11, |aw| < 2**7) are exact in f32, and a
 64-deep dot of 18-bit products stays < 2**24 — so the kernel is bit-exact
 vs. the integer reference (no rounding anywhere before the scale multiply).
 
-VMEM budget at the default bm=bn=128, bk=512 (f32 staging):
+VMEM budget at the default bm=bn=128 with bk from ``pick_bk`` (512 at
+yi-9b's K = 4096, f32 staging):
   ax 128×512×4 + aw 512×128×4 + acc 128×128×4 + scales ≈ 0.6 MiB « 16 MiB.
-bk covers 8 groups; the MXU sees K=64 per dot — on real hardware one would
+bk covers 8 groups there; the MXU sees K=64 per dot — on real hardware one would
 fuse 2 groups into a K=128 dot by pre-multiplying one operand's scale; that
 variant is `folded=True` (both validated against the same oracle).
 """
@@ -25,6 +26,9 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+from . import backend
+from .fp8_quant_align import pick_bk
 
 GROUP = 64
 
@@ -43,7 +47,7 @@ def _kernel(ax_ref, sx_ref, aw_ref, sw_ref, o_ref, *, groups_per_blk: int):
         a = ax_ref[:, g * GROUP : (g + 1) * GROUP].astype(jnp.float32)
         b = aw_ref[g * GROUP : (g + 1) * GROUP, :].astype(jnp.float32)
         part = jnp.dot(a, b, preferred_element_type=jnp.float32)
-        acc = acc + part * (sx_ref[:, g : g + 1] * sw_ref[g : g + 1, :])
+        acc = acc + part * (sx_ref[:, g * GROUP : g * GROUP + 1] * sw_ref[g])
     o_ref[...] = acc
 
 
@@ -59,7 +63,8 @@ def _kernel_folded(ax_ref, sx_ref, aw_ref, sw_ref, o_ref, *, groups_per_blk: int
     with s̃ the group scales broadcast along their 64 lanes.  This replaces
     bk/64 rank-64 dots + bk/64 scaled adds with ONE rank-bk MXU dot — the
     compute-term optimization (DESIGN.md §8; the fused one-pass kernel in
-    ``kernels/dsbp_fused.py`` builds on exactly this dot).
+    ``kernels/dsbp_fused.py`` builds on exactly this dot).  ``sx`` arrives
+    already repeated per lane, so the input side needs no group reshape.
     """
     kk = pl.program_id(2)
 
@@ -67,15 +72,12 @@ def _kernel_folded(ax_ref, sx_ref, aw_ref, sw_ref, o_ref, *, groups_per_blk: int
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    bm = ax_ref.shape[0]
-    bk = ax_ref.shape[1]
-    bn = aw_ref.shape[1]
-    gpb = groups_per_blk
-    a = ax_ref[...].astype(jnp.float32).reshape(bm, gpb, GROUP)
-    a = (a * sx_ref[...][:, :, None]).reshape(bm, bk)
+    gpb, _, bn = sw_ref.shape
+    a = ax_ref[...].astype(jnp.float32) * sx_ref[...]
     b = aw_ref[...].astype(jnp.float32).reshape(gpb, GROUP, bn)
-    b = (b * sw_ref[...][:, None, :]).reshape(bk, bn)
-    o_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32)
+    b = (b * sw_ref[...]).reshape(gpb * GROUP, bn)
+    o_ref[...] += jnp.dot(a, b, preferred_element_type=jnp.float32,
+                          precision=jax.lax.Precision.HIGHEST)
 
 
 @functools.partial(
@@ -89,8 +91,8 @@ def dsbp_matmul_kernel_call(
     *,
     bm: int = 128,
     bn: int = 128,
-    bk: int = 512,
-    interpret: bool = True,
+    bk: int | None = None,
+    interpret: bool | None = None,
     folded: bool = False,
 ):
     """Tiled pallas_call; N/K must divide by their block sizes.
@@ -105,14 +107,21 @@ def dsbp_matmul_kernel_call(
     11 magnitude bits + sign) while pack-once weights arrive as **int8**
     aligned mantissas (<= 7 magnitude bits + sign) straight from
     ``PackedDSBPWeight`` — both stage to f32 losslessly inside the kernel.
+
+    The kernel reads ``sx`` repeated per lane and ``sw`` as ``(ng, 1, N)``:
+    TPU blocks of those shapes are legal for every ``bk``, where
+    ``(bm, bk/64)`` and ``(bk/64, bn)`` ones are not.
     """
+    if interpret is None:
+        interpret = backend.interpret_default()
     m, k = ax.shape
     n = aw.shape[1]
     ng = k // GROUP
     assert jnp.issubdtype(ax.dtype, jnp.integer), ax.dtype
     assert jnp.issubdtype(aw.dtype, jnp.integer), aw.dtype
     assert k % GROUP == 0 and sx.shape == (m, ng) and sw.shape == (ng, n)
-    bm, bn, bk = min(bm, m), min(bn, n), min(bk, k)
+    bm, bn = min(bm, m), min(bn, n)
+    bk = pick_bk(k, bm, bn) if bk is None else min(bk, k)
     assert n % bn == 0 and k % bk == 0 and bk % GROUP == 0
     pad_m = (-m) % bm
     if pad_m:  # zero mantissa rows contribute 0 and are sliced away
@@ -120,18 +129,19 @@ def dsbp_matmul_kernel_call(
         sx = jnp.pad(sx, ((0, pad_m), (0, 0)))
     mp = m + pad_m
     gpb = bk // GROUP
+    sx = jnp.repeat(sx, GROUP, axis=1)
     body = _kernel_folded if folded else _kernel
     y = pl.pallas_call(
         functools.partial(body, groups_per_blk=gpb),
         grid=(mp // bm, n // bn, k // bk),
         in_specs=[
             pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
-            pl.BlockSpec((bm, gpb), lambda i, j, kk: (i, kk)),
+            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
-            pl.BlockSpec((gpb, bn), lambda i, j, kk: (kk, j)),
+            pl.BlockSpec((gpb, 1, bn), lambda i, j, kk: (kk, 0, j)),
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
         interpret=interpret,
-    )(ax, sx, aw, sw)
+    )(ax, sx, aw, sw.reshape(ng, 1, n))
     return y[:m] if pad_m else y

@@ -16,6 +16,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import backend
+
 __all__ = ["flash_attention_kernel_call", "paged_flash_attention_kernel_call",
            "packed_flash_attention_kernel_call",
            "paged_packed_flash_attention_kernel_call"]
@@ -76,7 +78,7 @@ def flash_attention_kernel_call(
     window: int | None = None,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     sq, d = q.shape
     skv = k.shape[0]
@@ -101,7 +103,8 @@ def flash_attention_kernel_call(
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # accumulator
         ],
-        interpret=interpret,
+        interpret=(backend.interpret_default() if interpret is None
+                   else interpret),
     )(q, k, v)
 
 
@@ -174,7 +177,7 @@ def packed_flash_attention_kernel_call(
     window: int | None = None,
     bq: int = 128,
     bkv: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Flash attention consuming a packed KV cache without a dequantize
     pass: the mantissa blocks stream int8 (4x less KV HBM traffic than f32)
@@ -207,7 +210,8 @@ def packed_flash_attention_kernel_call(
             pltpu.VMEM((bq, 1), jnp.float32),   # running sum
             pltpu.VMEM((bq, d), jnp.float32),   # accumulator
         ],
-        interpret=interpret,
+        interpret=(backend.interpret_default() if interpret is None
+                   else interpret),
     )(q, k_qm, k_scale, v_qm, v_scale)
 
 
@@ -272,7 +276,7 @@ def paged_flash_attention_kernel_call(
     window: int | None = None,
     q_start: int = 0,   # absolute position of q row 0 (decode/verify tail)
     bq: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Flash attention reading K/V straight out of a paged block pool.
 
@@ -317,7 +321,8 @@ def paged_flash_attention_kernel_call(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((sq, d), q.dtype),
-        interpret=interpret,
+        interpret=(backend.interpret_default() if interpret is None
+                   else interpret),
     )(table, q, k_pool, v_pool)
 
 
@@ -388,7 +393,7 @@ def paged_packed_flash_attention_kernel_call(
     window: int | None = None,
     q_start: int = 0,
     bq: int = 128,
-    interpret: bool = True,
+    interpret: bool | None = None,
 ):
     """Flash attention over a PACKED paged block pool: the block table
     rides the scalar-prefetch path exactly as in
@@ -427,5 +432,6 @@ def paged_packed_flash_attention_kernel_call(
         ),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((sq, d), q.dtype),
-        interpret=interpret,
+        interpret=(backend.interpret_default() if interpret is None
+                   else interpret),
     )(table, q, k_qm_pool, k_scale_pool, v_qm_pool, v_scale_pool)

@@ -1,8 +1,8 @@
 """Jit'd public wrappers around the Pallas kernels.
 
-On this CPU container the kernels execute with ``interpret=True`` (the
-kernel body runs in Python op-by-op); on a real TPU set
-``REPRO_PALLAS_INTERPRET=0`` (or pass interpret=False) to compile them.
+Every ``interpret`` argument defaults to None, which the kernel entry
+resolves from the backend (``kernels/backend.py``): compiled by Mosaic on a
+TPU, interpreted (the kernel body run op by op) on the CPU backend.
 
 Weight handling mirrors the macro (DESIGN.md §2/§8): ``dsbp_matmul_fused``
 is the serving entry point — ONE kernel runs quantize + predict + align +
@@ -15,7 +15,6 @@ pack-per-call convenience wrapper.
 """
 from __future__ import annotations
 
-import os
 from functools import partial
 
 import jax
@@ -33,7 +32,6 @@ from . import fp8_quant_align as _qa
 from . import flash_attention as _fa
 
 __all__ = [
-    "interpret_default",
     "dsbp_matmul",
     "dsbp_matmul_packed",
     "dsbp_matmul_fused",
@@ -84,15 +82,9 @@ def count_weight_transposes(fn, *args, min_size: int) -> int:
     return count
 
 
-def interpret_default() -> bool:
-    return os.environ.get("REPRO_PALLAS_INTERPRET", "1") != "0"
-
-
 @partial(jax.jit, static_argnames=("cfg", "interpret"))
 def fp8_quant_align(x: jax.Array, cfg: DSBPConfig, interpret: bool | None = None):
     """On-the-fly input path: (M,K) f32 -> aligned ints, scales, bits."""
-    if interpret is None:
-        interpret = interpret_default()
     ts = per_tensor_scale(x, cfg.fmt)
     a, s, b = _qa.fp8_quant_align_kernel_call(x * ts, cfg, interpret=interpret)
     return {"a": a, "scale": s, "bits": b, "tscale": ts}
@@ -115,8 +107,6 @@ def dsbp_matmul_packed(
     zero-padded here up to the packed (group-aligned) K', exactly mirroring
     the zero lanes the weights were packed with.
     """
-    if interpret is None:
-        interpret = interpret_default()
     _check_packed_2d(pw, x, "dsbp_matmul_packed")
     batch = x.shape[:-1]
     n = pw.n
@@ -167,8 +157,6 @@ def dsbp_matmul_fused(
     ``dsbp_matmul_ref`` under the default RNE path.  M is ragged-friendly
     (decode batches like B=3 auto-pad internally).
     """
-    if interpret is None:
-        interpret = interpret_default()
     _check_packed_2d(pw, x, "dsbp_matmul_fused")
     batch = x.shape[:-1]
     icfg = input_cfg if input_cfg is not None else pw.cfg.input_cfg
@@ -223,8 +211,6 @@ def dsbp_matmul_fused_sharded(
             x, pw, input_cfg=input_cfg, interpret=interpret,
             bm=bm, bn=bn, bk=bk,
         )
-    if interpret is None:
-        interpret = interpret_default()
     _check_packed_2d(pw, x, "dsbp_matmul_fused_sharded")
     batch = x.shape[:-1]
     icfg = input_cfg if input_cfg is not None else pw.cfg.input_cfg
@@ -333,8 +319,6 @@ dsbp_matmul_fused_ste.defvjp(_fused_ste_fwd, _ste_bwd)
 def flash_attention(q, k, v, *, causal=True, window=None, interpret=None,
                     bq=128, bkv=128):
     """(B, Hq, Sq, D) x (B, Hkv, S, D) GQA flash attention via vmap."""
-    if interpret is None:
-        interpret = interpret_default()
     b, hq, sq, d = q.shape
     hkv = k.shape[1]
     rep = hq // hkv
@@ -363,8 +347,6 @@ def packed_flash_attention(q, k, v, *, causal=True, window=None,
     (:func:`count_kv_dequants` == 0) and the KV HBM traffic is the packed
     bytes.  Bit-identical to :func:`flash_attention` over
     ``k.dequantize()``/``v.dequantize()`` (tests/test_kvq.py)."""
-    if interpret is None:
-        interpret = interpret_default()
     b, hq, sq, d = q.shape
     hkv = k.qm.shape[1]
     rep = hq // hkv

@@ -17,6 +17,7 @@ import argparse
 import jax
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.eval import harness
 from repro.models import model as M
 from repro.policy import autotune, calibrate, synthetic_calibration_batches
@@ -44,6 +45,7 @@ def main():
     ap.add_argument("--quant-method", default="dsbp_ref",
                     help="trial-engine method (dsbp_ref is fastest on CPU)")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = (smoke_config(args.arch) if args.smoke
            else get_config(args.arch)).replace(remat=False, dtype="float32")

@@ -5,7 +5,7 @@ touches jax device state — the dry-run sets XLA_FLAGS before first init.
 """
 from __future__ import annotations
 
-import jax
+from repro.parallel.sharding import make_mesh
 
 __all__ = ["make_production_mesh", "make_pipe_mesh"]
 
@@ -14,9 +14,9 @@ def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips multi-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_pipe_mesh(n_stages: int = 8):
     """Mesh for the pipeline-parallel library tests."""
-    return jax.make_mesh((n_stages,), ("pipe",))
+    return make_mesh((n_stages,), ("pipe",))

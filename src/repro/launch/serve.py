@@ -30,8 +30,10 @@ import jax
 import numpy as np
 
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import model as M
-from repro.serve.engine import Engine, Request, ServeConfig
+from repro.serve.engine import (Engine, Request, ServeConfig, init_packed,
+                                packed_nbytes)
 
 
 def main():
@@ -124,6 +126,7 @@ def main():
                          "the step through the dsbp_ref reference path "
                          "(DESIGN.md §13)")
     args = ap.parse_args()
+    setup_compile_cache()
     if args.deadline_steps or args.priority:
         args.ragged = True  # per-request lifecycle lives in serve()
     if args.spec_k or args.paged:
@@ -135,9 +138,15 @@ def main():
 
     cfg = (smoke_config(args.arch) if args.smoke
            else get_config(args.arch).replace(dtype="bfloat16")).replace(remat=False)
+    key = jax.random.PRNGKey(0)
+    pack_stats = None
     if args.packed:
+        # packed layer by layer: at published widths the float model may
+        # not fit the device (yi-9b: 17.7 GB in bf16 on a 16 GB v5e)
         cfg = cfg.replace(quant=args.preset)
-    params = M.init(jax.random.PRNGKey(0), cfg)
+        params, pack_stats = init_packed(key, cfg, args.preset)
+    else:
+        params = M.init(key, cfg)
 
     mesh_shape = mesh_axes = None
     if args.mesh:
@@ -176,11 +185,10 @@ def main():
     if eng.mesh is not None:
         print(f"mesh {dict(eng.mesh.shape)} over {eng.mesh.size} devices, "
               f"slot pool {eng.pool_size}")
-    if eng.pack_report:
-        rep = eng.pack_report
-        print(f"packed weights: {rep['raw_nbytes']/1e6:.1f} -> "
-              f"{rep['packed_nbytes']/1e6:.1f} MB "
-              f"(avg W bits {rep['avg_w_bits']:.2f}, preset {rep['preset']})")
+    if pack_stats:
+        print(f"packed weights: {packed_nbytes(eng.params)/1e6:.1f} MB "
+              f"(avg W bits {pack_stats['avg_w_bits']:.2f}, preset "
+              f"{args.preset})")
     rng = np.random.default_rng(0)
     if args.ragged:
         lens = rng.integers(args.prompt_len // 2, args.prompt_len + 1,

@@ -22,6 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.checkpoint import store
 from repro.configs import get_config, smoke_config
+from repro.launch.compile_cache import setup_compile_cache
 from repro.data.pipeline import DataConfig, SyntheticLM
 from repro.models import model as M
 from repro.optim import adamw
@@ -44,15 +45,16 @@ def main():
                     help="'auto' = all devices on one 'data' axis; "
                          "'DxM' = explicit (data, model) grid")
     args = ap.parse_args()
+    setup_compile_cache()
 
     cfg = (smoke_config(args.arch) if args.smoke else
            get_config(args.arch).replace(dtype="bfloat16"))
     n_dev = jax.device_count()
     if args.mesh == "auto":
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = SH.make_mesh((n_dev, 1), ("data", "model"))
     else:
         d, m = map(int, args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = SH.make_mesh((d, m), ("data", "model"))
     print(f"arch={cfg.name} params={cfg.param_count()/1e6:.1f}M "
           f"mesh={dict(mesh.shape)}")
 

@@ -23,6 +23,11 @@ __all__ = ["blockwise_attention", "decode_attention", "verify_attention",
            "gather_kv_view", "qk_logits", "pv_out"]
 
 NEG_INF = -1e30
+# Float dots ask for full precision: at the default precision a TPU rounds
+# f32 operands to bfloat16, which moved yi-9b's prefill logits by 12% of
+# their range against the reference and made the dense and paged
+# schedulers pick different tokens.  bfloat16 operands are unaffected.
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _scale_row(kv: PackedKVBlock, ndim: int) -> jax.Array:
@@ -44,9 +49,9 @@ def qk_logits(eq: str, qg: jax.Array, kv) -> jax.Array:
     """
     if isinstance(kv, PackedKVBlock):
         lg = jnp.einsum(eq, qg.astype(jnp.float32),
-                        kv.qm.astype(jnp.float32))
+                        kv.qm.astype(jnp.float32), precision=HIGHEST)
         return lg * _scale_row(kv, lg.ndim)
-    return jnp.einsum(eq, qg, kv).astype(jnp.float32)
+    return jnp.einsum(eq, qg, kv, precision=HIGHEST).astype(jnp.float32)
 
 
 def pv_out(eq: str, p: jax.Array, kv) -> jax.Array:
@@ -59,8 +64,8 @@ def pv_out(eq: str, p: jax.Array, kv) -> jax.Array:
     """
     if isinstance(kv, PackedKVBlock):
         return jnp.einsum(eq, p * _scale_row(kv, p.ndim),
-                          kv.qm.astype(jnp.float32))
-    return jnp.einsum(eq, p, kv.astype(jnp.float32))
+                          kv.qm.astype(jnp.float32), precision=HIGHEST)
+    return jnp.einsum(eq, p, kv.astype(jnp.float32), precision=HIGHEST)
 
 
 def gather_kv_view(pool: jax.Array, table: jax.Array, s_c: int) -> jax.Array:
@@ -92,7 +97,8 @@ def gather_kv_view(pool: jax.Array, table: jax.Array, s_c: int) -> jax.Array:
 def _attend_block(q, k, v, qpos, kpos, kv_len, causal, window, state,
                   kv_lens=None):
     m_prev, l_prev, acc = state
-    s = jnp.einsum("bhqd,bhkd->bhqk", q, k).astype(jnp.float32)
+    s = jnp.einsum("bhqd,bhkd->bhqk", q, k,
+                   precision=HIGHEST).astype(jnp.float32)
     mask = jnp.broadcast_to(kpos[None, :] < kv_len, s.shape[-2:])
     if causal:
         mask &= kpos[None, :] <= qpos[:, None]
@@ -105,7 +111,8 @@ def _attend_block(q, k, v, qpos, kpos, kv_len, causal, window, state,
     p = jnp.exp(s - m_cur[..., None])
     alpha = jnp.exp(m_prev - m_cur)
     l_cur = l_prev * alpha + jnp.sum(p, axis=-1)
-    acc = acc * alpha[..., None] + jnp.einsum("bhqk,bhkd->bhqd", p, v.astype(jnp.float32))
+    acc = acc * alpha[..., None] + jnp.einsum(
+        "bhqk,bhkd->bhqd", p, v.astype(jnp.float32), precision=HIGHEST)
     return m_cur, l_cur, acc
 
 

@@ -22,6 +22,7 @@ from repro.configs import ArchConfig
 from repro.kvq import kv_policy_cfg
 
 from . import blocks
+from .attention import HIGHEST
 from .layers import Quant, init_norm, rms_norm
 
 __all__ = ["init", "forward", "loss_fn", "init_cache", "prefill",
@@ -43,7 +44,32 @@ def _vocab_rows(cfg) -> int:
 
 # ---------------- init ----------------
 
-def init(key, cfg: ArchConfig):
+def _keep(path, layer):
+    del path
+    return layer
+
+
+@partial(jax.jit, static_argnames=("cfg", "kind", "dt", "path", "layer_fn"))
+def _init_units(keys, cfg, kind, dt, path, layer_fn):
+    """One pattern position's stacked units, one layer per ``lax.map``
+    step: only ``layer_fn``'s output is ever stacked."""
+    return jax.lax.map(
+        lambda k: layer_fn(path, blocks.init_layer(k, cfg, kind, dt)), keys)
+
+
+def init(key, cfg: ArchConfig, layer_fn=_keep):
+    """Random parameters for ``cfg``.
+
+    ``layer_fn(path, layer_params)`` maps each layer's fresh parameters as
+    soon as they exist (``path`` is ``("units", "<li>")`` or
+    ``("tail", "<i>")``).  The stacked units are built one layer at a time
+    inside a jitted ``lax.map``, so only ``layer_fn``'s outputs are ever
+    stacked: with a packing ``layer_fn`` (``serve.engine.init_packed``)
+    the float model never exists on the device.  Every layer draws the
+    same key and runs the same program whatever ``layer_fn`` is, so
+    ``init(key, cfg, f)`` is ``f`` applied to each layer of
+    ``init(key, cfg)``.
+    """
     dt = _dtype(cfg)
     keys = jax.random.split(key, cfg.n_layers + 3)
     d = cfg.d_model
@@ -57,16 +83,17 @@ def init(key, cfg: ArchConfig):
             jax.random.normal(keys[1], (d, _vocab_rows(cfg)), jnp.float32) * d**-0.5
         ).astype(dt)
 
-    pat = cfg.pattern
-    ki = iter(keys[2:])
+    pat, n_units = cfg.pattern, cfg.n_units
     # stacked unit params: per pattern position, a pytree with leading n_units
-    unit_layers = []
-    for li, kind in enumerate(pat):
-        per_unit = [blocks.init_layer(next(ki), cfg, kind, dt) for _ in range(cfg.n_units)]
-        unit_layers.append(jax.tree.map(lambda *xs: jnp.stack(xs), *per_unit))
-    params["units"] = unit_layers
+    params["units"] = [
+        _init_units(keys[2 + li * n_units: 2 + (li + 1) * n_units], cfg,
+                    kind, dt, ("units", str(li)), layer_fn)
+        for li, kind in enumerate(pat)
+    ]
+    tail_keys = keys[2 + len(pat) * n_units:]
     params["tail"] = [
-        blocks.init_layer(next(ki), cfg, kind, dt) for kind in cfg.tail
+        layer_fn(("tail", str(i)), blocks.init_layer(k, cfg, kind, dt))
+        for i, (k, kind) in enumerate(zip(tail_keys, cfg.tail))
     ]
     return params
 
@@ -101,7 +128,8 @@ def _head(params, x, cfg):
 
     w = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     w = constrain(w, None, "model")  # vocab-sharded head (_GATHERED rule)
-    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype))
+    logits = jnp.einsum("bsd,dv->bsv", x, w.astype(x.dtype),
+                        precision=HIGHEST)
     vp, v = cfg.padded_vocab_size, cfg.vocab_size
     if vp != v:
         valid = (jnp.arange(logits.shape[-1]) % vp) < v

@@ -16,7 +16,6 @@ from functools import partial
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 __all__ = ["pipeline_apply", "bubble_fraction"]
 
@@ -74,11 +73,11 @@ def pipeline_apply(stage_fn, stage_params, x_micro, mesh: Mesh,
         outs = jax.lax.psum(outs, axis)
         return outs
 
-    fn = shard_map(
+    fn = jax.shard_map(
         body,
         mesh=mesh,
         in_specs=(P(axis), P()),
         out_specs=P(),
-        check_rep=False,
+        check_vma=False,
     )
     return fn(stage_params, x_micro)

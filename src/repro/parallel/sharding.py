@@ -23,7 +23,18 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = ["param_pspecs", "batch_pspecs", "cache_pspecs", "serve_pspecs",
-           "named", "batch_axes"]
+           "named", "batch_axes", "make_mesh"]
+
+
+def make_mesh(shape, axes, devices=None) -> Mesh:
+    """``jax.make_mesh`` with every axis Auto — the mesh the ``with mesh:``
+    / NamedSharding / shard_map code of this repo is written for
+    (``jax.make_mesh`` itself defaults to Explicit axes)."""
+    from jax.sharding import AxisType
+
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
 
 
 def batch_axes(mesh: Mesh):

@@ -36,15 +36,16 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import ArchConfig
-from repro.core.packed import (key_entry_str, pack_weights_sharded,
-                               packed_nbytes, tree_is_packed)
+from repro.core.packed import (PackedDSBPWeight, key_entry_str,
+                               pack_weights_sharded, packed_nbytes,
+                               tree_is_packed)
 from repro.core.quantized import PRESETS, pack_weights
 from repro.kvq import is_kv_leaf_path, kv_cache_nbytes, tree_has_packed_kv
 from repro.models import model as M
 from repro.obs import ServeRecorder
 
 __all__ = ["ServeConfig", "Request", "Engine", "pack_weights_int8",
-           "packed_nbytes", "sample_tokens"]
+           "pack_tree", "init_packed", "packed_nbytes", "sample_tokens"]
 
 # terminal request lifecycle states (DESIGN.md §13); every served uid ends
 # in exactly one of these, reported via last_stats["request_status"]
@@ -245,6 +246,26 @@ def pack_weights_int8(params, preset="precise", mesh=None):
     only its own output-column shard under shard_map, so the full-size
     container is never materialized on one device (bit-identical to
     pack-then-shard, DESIGN.md §11)."""
+    packed = pack_tree(params, preset, mesh)
+    return packed, _pack_stats(packed)
+
+
+def init_packed(key, cfg: ArchConfig, preset="precise", mesh=None):
+    """``pack_weights_int8(M.init(key, cfg), preset, mesh)`` built layer by
+    layer: each layer's float weights are generated and packed at once
+    (``M.init``'s ``layer_fn``), so the float model — 17.7 GB for yi-9b in
+    bf16, more than one v5e holds — never exists on the device; only the
+    packed stack (~9.9 GB for yi-9b) does.  Bit-identical to packing the
+    whole float tree (tests/test_packed.py)."""
+    packed = M.init(key, cfg, layer_fn=lambda path, layer: pack_tree(
+        layer, preset, mesh, prefix=path))
+    return packed, _pack_stats(packed)
+
+
+def pack_tree(params, preset="precise", mesh=None, prefix=()):
+    """:func:`pack_weights_int8` without the statistics, so it also runs
+    under a trace: packs the projection leaves of ``params``, whose paths
+    are relative to ``prefix`` (a policy keys projections by full path)."""
     policy = preset if hasattr(preset, "config_for") else None
     cfg0 = None
     if policy is None:
@@ -258,30 +279,35 @@ def pack_weights_int8(params, preset="precise", mesh=None):
             cfg0 = PRESETS[preset]
         else:
             cfg0 = preset
-    stats = {"bits_sum": 0.0, "groups": 0, "layers": 0}
 
     def pack(path, leaf):
         name = str(getattr(path[-1], "key", ""))
         if name not in PROJ_NAMES or getattr(leaf, "ndim", 0) < 2:
             return leaf
         if policy is not None:
-            cfg = policy.config_for("/".join(key_entry_str(p) for p in path))
+            cfg = policy.config_for("/".join(
+                [*prefix, *(key_entry_str(p) for p in path)]))
             if cfg is None:
                 return leaf
         else:
             cfg = cfg0
         if leaf.shape[-2] < cfg.weight_cfg.group_size:
             return leaf
-        pw = (pack_weights_sharded(leaf, cfg, mesh) if mesh is not None
-              else pack_weights(leaf, cfg))
-        stats["bits_sum"] += float(jnp.sum(pw.bits.astype(jnp.int32) + 1))
-        stats["groups"] += int(np.prod(pw.bits.shape))
-        stats["layers"] += 1
-        return pw
+        return (pack_weights_sharded(leaf, cfg, mesh) if mesh is not None
+                else pack_weights(leaf, cfg))
 
-    packed = jax.tree_util.tree_map_with_path(pack, params)
-    avg_w_bits = stats["bits_sum"] / max(stats["groups"], 1)
-    return packed, {"avg_w_bits": avg_w_bits, "layers_packed": stats["layers"]}
+    return jax.tree_util.tree_map_with_path(pack, params)
+
+
+def _pack_stats(packed) -> dict:
+    is_pw = lambda x: isinstance(x, PackedDSBPWeight)
+    bits_sum, groups, layers = 0.0, 0, 0
+    for leaf in jax.tree.leaves(packed, is_leaf=is_pw):
+        if is_pw(leaf):
+            bits_sum += float(jnp.sum(leaf.bits.astype(jnp.int32) + 1))
+            groups += int(np.prod(leaf.bits.shape))
+            layers += 1
+    return {"avg_w_bits": bits_sum / max(groups, 1), "layers_packed": layers}
 
 
 def sample_tokens(logits, cfg: ArchConfig, temperature: float = 0.0,
@@ -616,10 +642,9 @@ class Engine:
                 f"mesh_shape {shape} needs {n} devices; "
                 f"{jax.device_count()} available (simulate CPU devices with "
                 f"XLA_FLAGS=--xla_force_host_platform_device_count=N)")
-        from jax.sharding import Mesh
+        from repro.parallel.sharding import make_mesh
 
-        return Mesh(np.asarray(jax.devices()[:n]).reshape(shape),
-                    scfg.mesh_axes)
+        return make_mesh(shape, scfg.mesh_axes, devices=jax.devices()[:n])
 
     def _trace_ctx(self):
         """Sharding context entered while tracing every model call: the

@@ -197,3 +197,27 @@ def test_engine_packs_once_not_per_generate():
     # an already-packed tree passed in is served as-is
     eng2 = Engine(eng.params, cfg, ServeConfig(max_len=64))
     assert eng2.pack_report is None and eng2.params is eng.params
+
+
+@pytest.mark.parametrize("arch", ["yi-9b", "recurrentgemma-2b",
+                                  "mixtral-8x7b"])
+def test_init_packed_equals_packing_the_float_model(arch):
+    """The layer-by-layer build (each layer packed as soon as it exists, so
+    the float model never does) gives bit-identical containers to packing
+    what M.init returns — stacked units, unrolled tail layers and MoE
+    expert stacks alike — and the same bit statistics."""
+    from repro.configs import smoke_config
+    from repro.serve.engine import init_packed
+
+    cfg = smoke_config(arch).replace(quant="precise")
+    key = jax.random.PRNGKey(3)
+    want, want_stats = pack_weights_int8(M.init(key, cfg), "precise")
+    got, got_stats = init_packed(key, cfg, "precise")
+    is_pw = lambda x: isinstance(x, PackedDSBPWeight)
+    assert (jax.tree.structure(got, is_leaf=is_pw)
+            == jax.tree.structure(want, is_leaf=is_pw))
+    assert tree_is_packed(got)
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert got_stats == want_stats

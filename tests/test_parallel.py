@@ -16,7 +16,7 @@ def _run(body: str):
         import sys; sys.path.insert(0, "src")
         import numpy as np
         import jax, jax.numpy as jnp
-        from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
         """
     ) + textwrap.dedent(body)
     r = subprocess.run([sys.executable, "-c", src], capture_output=True,
@@ -88,10 +88,10 @@ def test_pipeline_parallel_matches_sequential():
 def test_compressed_psum_matches_plain_within_tolerance():
     _run("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.train.grad_compress import psum_compressed
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(1)
     g = jnp.asarray(rng.standard_normal((8, 512)).astype(np.float32) * 0.01)
 
@@ -117,7 +117,7 @@ def test_expert_parallel_moe_shard_map():
     single-device grouped-dispatch MoE."""
     _run("""
     from functools import partial
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from repro.configs import smoke_config
     from repro.models import moe as MOE
 
@@ -127,7 +127,7 @@ def test_expert_parallel_moe_shard_map():
     x = jnp.asarray(rng.standard_normal((4, 16, cfg.d_model)).astype(np.float32))
     ref = MOE.moe_ffn(params, x, cfg, no_drop=True)
 
-    mesh = jax.make_mesh((8,), ("expert",))
+    mesh = jax.make_mesh((8,), ("expert",), axis_types=(AxisType.Auto,))
     # shard expert-leading params over the expert axis; replicate x;
     # each member computes its experts' contribution, psum combines.
     def ep_moe(p_local, xx):
@@ -164,7 +164,7 @@ def test_long_context_sequence_sharded_decode_attention():
     _run("""
     from repro.models.attention import decode_attention
 
-    mesh = jax.make_mesh((8,), ("data",))
+    mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
     rng = np.random.default_rng(3)
     q = jnp.asarray(rng.standard_normal((1, 4, 1, 32)).astype(np.float32))
     k = jnp.asarray(rng.standard_normal((1, 2, 512, 32)).astype(np.float32))

@@ -263,3 +263,33 @@ def test_serve_parity_paged_vs_dense_under_mesh():
         assert p.last_stats["stalled_decode_steps"] == 0
     print("paged-vs-dense parity OK on 1 device and (2,4) mesh")
     """)
+
+
+def test_init_packed_on_mesh_equals_packing_the_float_model():
+    """Layer-by-layer build on a 4-device (data=1, model=4) mesh: every
+    projection packs through pack_weights_sharded into column shards, and
+    the result equals pack_weights_int8(M.init(...)) on one device."""
+    _run("""
+    from repro.configs import smoke_config
+    from repro.core.packed import PackedDSBPWeight
+    from repro.models import model as M
+    from repro.parallel.sharding import make_mesh
+    from repro.serve.engine import init_packed, pack_weights_int8
+
+    cfg = smoke_config("yi-9b").replace(quant="precise")
+    mesh = make_mesh((1, 4), ("data", "model"), devices=jax.devices()[:4])
+    key = jax.random.PRNGKey(5)
+    want, want_stats = pack_weights_int8(M.init(key, cfg), "precise")
+    got, got_stats = init_packed(key, cfg, "precise", mesh=mesh)
+    is_pw = lambda x: isinstance(x, PackedDSBPWeight)
+    assert (jax.tree.structure(got, is_leaf=is_pw)
+            == jax.tree.structure(want, is_leaf=is_pw))
+    sharded = 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert a.shape == b.shape and a.dtype == b.dtype
+        assert np.array_equal(np.asarray(a), np.asarray(b))
+        sharded += len(a.sharding.device_set) == 4
+    assert sharded > 0, "no leaf was packed into shards"
+    assert got_stats == want_stats
+    print("mesh init_packed OK")
+    """)
