@@ -1,0 +1,13 @@
+"""Device time of the paged KV read (the model's ``kv_gather`` scope: the
+block-table gather of every lane's cache view) per paged decode program
+run, from the ops' scopes in the trace (``bench/program_trace.py``).
+Moves time per output token."""
+
+from bench import program_trace
+
+program_trace.attach()
+
+
+def read(run):
+    return program_trace.scope_ms_per_run(run, "_decode_paged_fn",
+                                          "kv_gather")

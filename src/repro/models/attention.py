@@ -68,6 +68,7 @@ def pv_out(eq: str, p: jax.Array, kv) -> jax.Array:
     return jnp.einsum(eq, p, kv.astype(jnp.float32), precision=HIGHEST)
 
 
+@jax.named_scope("kv_gather")
 def gather_kv_view(pool: jax.Array, table: jax.Array, s_c: int) -> jax.Array:
     """Materialize a dense per-lane cache view from a paged block pool.
 
@@ -120,6 +121,7 @@ def _attend_block(q, k, v, qpos, kpos, kv_len, causal, window, state,
     jax.jit,
     static_argnames=("causal", "window", "bq", "bkv", "q_offset"),
 )
+@jax.named_scope("attention")
 def blockwise_attention(
     q: jax.Array,  # (B, Hq, Sq, D)
     k: jax.Array,  # (B, Hkv, Skv, D)
@@ -177,6 +179,7 @@ def blockwise_attention(
 
 
 @partial(jax.jit, static_argnames=("window",))
+@jax.named_scope("attention")
 def verify_attention(
     q: jax.Array,        # (B, Hq, T, D)  T speculated tokens per row
     k_new: jax.Array,    # (B, Hkv, T, D) their keys (NOT yet in the cache)
@@ -224,6 +227,7 @@ def verify_attention(
 
 
 @partial(jax.jit, static_argnames=("window",))
+@jax.named_scope("attention")
 def decode_attention(
     q: jax.Array,  # (B, Hq, 1, D)
     k_cache: jax.Array,  # (B, Hkv, S, D)

@@ -89,10 +89,12 @@ def init_layer(key, cfg, kind: str, dtype):
 # ---------------- ffn ----------------
 
 def _ffn(params, x, quant):
-    h1 = dense(params["w1"], x, quant, name="w1")
-    h3 = dense(params["w3"], x, quant, name="w3")
-    h = jax.nn.silu(h1.astype(jnp.float32)).astype(x.dtype) * h3
-    return dense(params["w2"], h, quant, name="w2")
+    with jax.named_scope("mlp_in"):
+        h1 = dense(params["w1"], x, quant, name="w1")
+        h3 = dense(params["w3"], x, quant, name="w3")
+        h = jax.nn.silu(h1.astype(jnp.float32)).astype(x.dtype) * h3
+    with jax.named_scope("mlp_out"):
+        return dense(params["w2"], h, quant, name="w2")
 
 
 def _mlp_part(params, x, cfg, quant, no_drop=False):
@@ -104,6 +106,7 @@ def _mlp_part(params, x, cfg, quant, no_drop=False):
 
 # ---------------- attention, sequence mode ----------------
 
+@jax.named_scope("qkv")
 def _qkv(params, y, cfg, quant, positions):
     b, s, _ = y.shape
     dh = cfg.d_head
@@ -115,14 +118,20 @@ def _qkv(params, y, cfg, quant, positions):
     return q, k, v.transpose(0, 2, 1, 3)
 
 
+@jax.named_scope("attn_out")
+def _attn_out(params, o, x, cfg, quant):
+    """``x`` plus the output projection of the heads ``o`` (B, Hq, S, D)."""
+    b, _, s, _ = o.shape
+    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.d_head)
+    return x + dense(params["wo"], o.astype(x.dtype), quant, name="wo")
+
+
 def _attn_seq(params, x, cfg, kind, quant, positions, lengths=None):
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
     q, k, v = _qkv(params["attn"], y, cfg, quant, positions)
     window = cfg.window if kind == "attn_local" else 0
     o = blockwise_attention(q, k, v, causal=True, window=window, kv_lens=lengths)
-    b, s, _ = x.shape
-    o = o.transpose(0, 2, 1, 3).reshape(b, s, cfg.n_heads * cfg.d_head)
-    x = x + dense(params["attn"]["wo"], o.astype(x.dtype), quant, name="wo")
+    x = _attn_out(params["attn"], o, x, cfg, quant)
     return x, (k, v)
 
 
@@ -264,6 +273,7 @@ def _scatter_pool(pool_leaf, table, slots, vals, mask):
                   pool_leaf[phys, :, off]).astype(pool_leaf.dtype))
 
 
+@jax.named_scope("kv_write")
 def write_kv_blocks(pool, table, k, v, pos, write_len, s_c: int,
                     write_start=None):
     """Write T fresh K/V entries per row through the block table — the ONE
@@ -300,6 +310,7 @@ def write_kv_blocks(pool, table, k, v, pos, write_len, s_c: int,
     return {"k": wr(pool["k"], k), "v": wr(pool["v"], v)}
 
 
+@jax.named_scope("kv_write")
 def fill_kv_cache_paged(pool, table, k, v, lengths, s_c: int,
                         write_start=None):
     """Prefill fill as a block-table scatter: the same per-ring-slot
@@ -372,11 +383,11 @@ def _attn_decode(params, x, cfg, kind, quant, cache, pos):
         o = _ring_decode_attention(q, ck, cv, valid)
     else:
         o = decode_attention(q, ck, cv, posb + 1, window=0)
-    o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * cfg.d_head)
-    x = x + dense(params["attn"]["wo"], o.astype(x.dtype), quant, name="wo")
+    x = _attn_out(params["attn"], o, x, cfg, quant)
     return x, {"k": ck, "v": cv}
 
 
+@jax.named_scope("attention")
 def _ring_decode_attention(q, k_cache, v_cache, valid):
     b, hq, _, d = q.shape
     hkv, s = k_cache.shape[1], k_cache.shape[2]
@@ -398,7 +409,6 @@ def _attn_decode_paged(params, x, cfg, kind, quant, pool, table, posb,
     for every lane with ``write_len`` 1.  Lanes with ``write_len`` 0
     (idle, or mid-chunked-prefill during a decode step) write nothing and
     their output is discarded by the engine."""
-    b = x.shape[0]
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
     q, k, v = _qkv(params["attn"], y, cfg, quant, posb[:, None])
     pool = write_kv_blocks(pool, table, k, v, posb, write_len, s_c)
@@ -411,8 +421,7 @@ def _attn_decode_paged(params, x, cfg, kind, quant, pool, table, posb,
         o = _ring_decode_attention(q, ck, cv, valid)
     else:
         o = decode_attention(q, ck, cv, posb + 1, window=0)
-    o = o.transpose(0, 2, 1, 3).reshape(b, 1, cfg.n_heads * cfg.d_head)
-    x = x + dense(params["attn"]["wo"], o.astype(x.dtype), quant, name="wo")
+    x = _attn_out(params["attn"], o, x, cfg, quant)
     return x, pool
 
 
@@ -432,8 +441,7 @@ def _attn_verify(params, x, cfg, kind, quant, cache, posb):
     kq = quantize_like(cache["k"], k)
     vq = quantize_like(cache["v"], v)
     o = verify_attention(q, kq, vq, cache["k"], cache["v"], posb, window=window)
-    o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.n_heads * cfg.d_head)
-    x = x + dense(params["attn"]["wo"], o.astype(x.dtype), quant, name="wo")
+    x = _attn_out(params["attn"], o, x, cfg, quant)
     s_c = cache["k"].shape[2]
     slots = positions % s_c  # distinct while T <= S_c (engine contract)
     bidx = jnp.arange(b)[:, None]
@@ -486,7 +494,7 @@ def _attn_verify_paged(params, x, cfg, kind, quant, pool, table, posb,
     Commit-on-accept replaces dense write-then-rollback: the pool never
     holds rejected entries, so rollback is bit-exact by construction and
     no pre-step pool copy is kept alive."""
-    b, t, _ = x.shape
+    t = x.shape[1]
     positions = posb[:, None] + jnp.arange(t)[None, :]  # (B, T)
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
     q, k, v = _qkv(params["attn"], y, cfg, quant, positions)
@@ -499,8 +507,7 @@ def _attn_verify_paged(params, x, cfg, kind, quant, pool, table, posb,
     kq = quantize_like(pool["k"], k)
     vq = quantize_like(pool["v"], v)
     o = verify_attention(q, kq, vq, ck, cv, posb, window=window)
-    o = o.transpose(0, 2, 1, 3).reshape(b, t, cfg.n_heads * cfg.d_head)
-    x = x + dense(params["attn"]["wo"], o.astype(x.dtype), quant, name="wo")
+    x = _attn_out(params["attn"], o, x, cfg, quant)
     return x, {"k": kq, "v": vq}
 
 

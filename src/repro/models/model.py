@@ -118,6 +118,7 @@ def embed_tokens(params, batch: dict, cfg: ArchConfig):
     return x, positions
 
 
+@jax.named_scope("lm_head")
 def _head(params, x, cfg):
     """Logits over the PADDED vocab; padded rows masked to -inf.
 
@@ -138,6 +139,15 @@ def _head(params, x, cfg):
 
 
 # ---------------- sequence-mode stack ----------------
+
+def _scan_layers(body, x, xs, cfg: ArchConfig):
+    """``lax.scan`` of ``body`` over the stacked pattern units, under the
+    ``layer_stack`` scope: its own ops (the per-layer slices of the stacked
+    weights and caches, the restacked outputs, and the norms and residual
+    adds between the named parts) carry that name in the trace."""
+    with jax.named_scope("layer_stack"):
+        return jax.lax.scan(body, x, xs, unroll=cfg.scan_unroll)
+
 
 def _unit_seq(unit_params, x, cfg, quant, positions, with_cache: bool,
               no_drop: bool = False, lengths=None):
@@ -165,8 +175,7 @@ def forward(params, batch: dict, cfg: ArchConfig, collect_cache: bool = False,
         return xx, auxs
 
     body = jax.checkpoint(unit_body) if cfg.remat else unit_body
-    x, unit_auxs = jax.lax.scan(body, x, tuple(params["units"]),
-                                unroll=cfg.scan_unroll)
+    x, unit_auxs = _scan_layers(body, x, tuple(params["units"]), cfg)
     tail_auxs = []
     for p_layer, kind in zip(params["tail"], cfg.tail):
         x, aux = blocks.layer_seq(p_layer, x, cfg, kind, quant, positions,
@@ -250,8 +259,7 @@ def _prefill_trunk(params, batch: dict, cfg: ArchConfig, lengths=None):
         return xx, auxs
 
     body = jax.checkpoint(unit_body) if cfg.remat else unit_body
-    x, unit_auxs = jax.lax.scan(body, x, tuple(params["units"]),
-                                unroll=cfg.scan_unroll)
+    x, unit_auxs = _scan_layers(body, x, tuple(params["units"]), cfg)
     tail_auxs = []
     for p_layer, kind in zip(params["tail"], cfg.tail):
         x, aux = blocks.layer_seq(p_layer, x, cfg, kind, quant, positions,
@@ -415,10 +423,8 @@ def decode_step(params, token_batch: dict, cache, pos, cfg: ArchConfig):
             new_caches.append(nc)
         return xc, tuple(new_caches)
 
-    x, new_unit_caches = jax.lax.scan(
-        unit_body, x, (tuple(params["units"]), tuple(cache["units"])),
-        unroll=cfg.scan_unroll,
-    )
+    x, new_unit_caches = _scan_layers(
+        unit_body, x, (tuple(params["units"]), tuple(cache["units"])), cfg)
     new_tail = []
     for i, kind in enumerate(cfg.tail):
         x, nc = blocks.layer_decode(
@@ -457,10 +463,8 @@ def decode_step_paged(params, token_batch: dict, cache, table, pos, write_len,
             new_caches.append(nc)
         return xc, tuple(new_caches)
 
-    x, new_unit_caches = jax.lax.scan(
-        unit_body, x, (tuple(params["units"]), tuple(cache["units"])),
-        unroll=cfg.scan_unroll,
-    )
+    x, new_unit_caches = _scan_layers(
+        unit_body, x, (tuple(params["units"]), tuple(cache["units"])), cfg)
     new_tail = []
     for i, kind in enumerate(cfg.tail):
         x, nc = blocks.layer_decode_paged(
@@ -510,10 +514,8 @@ def verify_step(params, token_batch: dict, cache, pos, cfg: ArchConfig,
             steps.append(st)
         return xc, (tuple(new_caches), tuple(steps))
 
-    x, (new_unit_caches, unit_steps) = jax.lax.scan(
-        unit_body, x, (tuple(params["units"]), tuple(cache["units"])),
-        unroll=cfg.scan_unroll,
-    )
+    x, (new_unit_caches, unit_steps) = _scan_layers(
+        unit_body, x, (tuple(params["units"]), tuple(cache["units"])), cfg)
     new_tail, tail_steps = [], []
     for i, kind in enumerate(cfg.tail):
         x, nc, st = blocks.layer_verify(
@@ -590,10 +592,8 @@ def verify_step_paged(params, token_batch: dict, cache, table, pos,
             steps.append(st)
         return xc, tuple(steps)
 
-    x, unit_steps = jax.lax.scan(
-        unit_body, x, (tuple(params["units"]), tuple(cache["units"])),
-        unroll=cfg.scan_unroll,
-    )
+    x, unit_steps = _scan_layers(
+        unit_body, x, (tuple(params["units"]), tuple(cache["units"])), cfg)
     tail_steps = []
     for i, kind in enumerate(cfg.tail):
         x, st = blocks.layer_verify_paged(
@@ -606,6 +606,7 @@ def verify_step_paged(params, token_batch: dict, cache, table, pos,
     return logits, {"units": list(unit_steps), "tail": tail_steps}
 
 
+@jax.named_scope("kv_write")
 def rollback_cache_paged(cache, table, steps, keep, pos, cfg: ArchConfig,
                          max_len: int):
     """Commit the accepted prefix of a :func:`verify_step_paged` round: KV

@@ -12,9 +12,8 @@ Design constraints (DESIGN.md §15):
   the observe path is one bisect.  Bucket semantics follow Prometheus:
   bucket ``i`` counts observations with ``value <= bound[i]`` exclusive of
   lower bounds, plus an implicit ``+Inf`` overflow bucket.
-- **Two exports, one source of truth.**  :meth:`MetricsRegistry.snapshot`
-  emits a JSON-able dict that round-trips via :meth:`from_snapshot`;
-  :meth:`to_prometheus` renders the standard text exposition format.
+- **One export.**  :meth:`MetricsRegistry.snapshot` emits a JSON-able
+  dict (what ``launch/serve.py --metrics-json`` writes).
 """
 from __future__ import annotations
 
@@ -98,13 +97,6 @@ class Histogram:
         return out
 
 
-_KINDS = {"counter": Counter, "gauge": Gauge, "histogram": Histogram}
-
-
-def _fmt(v: float) -> str:
-    return f"{int(v)}" if float(v).is_integer() else f"{v:.10g}"
-
-
 class MetricsRegistry:
     """Name → labelled-series families of counters/gauges/histograms."""
 
@@ -170,44 +162,3 @@ class MetricsRegistry:
             fams[name] = {"kind": fam["kind"], "help": fam["help"],
                           "series": series}
         return {"version": 1, "families": fams}
-
-    @classmethod
-    def from_snapshot(cls, snap: dict) -> "MetricsRegistry":
-        reg = cls()
-        for name, fam in snap["families"].items():
-            for row in fam["series"]:
-                labels = row["labels"]
-                if fam["kind"] == "histogram":
-                    h = reg.histogram(name, buckets=row["buckets"],
-                                      help=fam["help"], **labels)
-                    h.counts = list(row["counts"])
-                    h.sum, h.count = row["sum"], row["count"]
-                else:
-                    inst = reg._series(fam["kind"], name, fam["help"],
-                                       labels, _KINDS[fam["kind"]])
-                    inst.value = row["value"]
-        return reg
-
-    def to_prometheus(self) -> str:
-        """Standard text exposition format (one family per # TYPE block)."""
-        lines = []
-        for name in sorted(self._families):
-            fam = self._families[name]
-            if fam["help"]:
-                lines.append(f"# HELP {name} {fam['help']}")
-            lines.append(f"# TYPE {name} {fam['kind']}")
-            for key in sorted(fam["series"]):
-                inst = fam["series"][key]
-                base = ",".join(f'{k}="{v}"' for k, v in key)
-                if isinstance(inst, Histogram):
-                    for bound, cum in inst.cumulative():
-                        le = bound if bound == "+Inf" else _fmt(bound)
-                        lab = f'{base},le="{le}"' if base else f'le="{le}"'
-                        lines.append(f"{name}_bucket{{{lab}}} {cum}")
-                    suffix = f"{{{base}}}" if base else ""
-                    lines.append(f"{name}_sum{suffix} {_fmt(inst.sum)}")
-                    lines.append(f"{name}_count{suffix} {inst.count}")
-                else:
-                    suffix = f"{{{base}}}" if base else ""
-                    lines.append(f"{name}{suffix} {_fmt(inst.value)}")
-        return "\n".join(lines) + "\n"

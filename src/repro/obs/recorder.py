@@ -156,6 +156,16 @@ class ServeRecorder:
             help="wall time of one pool decode step").observe(dur_s)
         self.trace.instant(None, "decode-step", step, lanes=int(lanes))
 
+    def kv_read(self, step, gathered_rows, live_rows, row_bytes) -> None:
+        """KV rows one paged decode step gathers (every lane's whole table,
+        per KV layer) and the rows of them its decoding lanes hold."""
+        if not self.enabled:
+            return
+        self.metrics.counter("serve_kv_rows_gathered_total").inc(gathered_rows)
+        self.metrics.counter("serve_kv_rows_live_total").inc(live_rows)
+        self.metrics.counter("serve_kv_bytes_gathered_total").inc(
+            gathered_rows * row_bytes)
+
     def spec_round(self, step, keeps) -> None:
         if not self.enabled:
             return

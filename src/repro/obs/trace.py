@@ -11,6 +11,12 @@ so the stream renders directly in Perfetto / chrome://tracing via
 :meth:`TraceRecorder.to_chrome` — one pseudo-thread per uid, tid 0 for
 scheduler-scope events (decode steps, fault injections).
 
+:func:`span` is the other half: a ``jax.profiler.TraceAnnotation`` the
+schedulers open around each phase of an iteration (``serve.*``) and each
+scoring call (``score.*``), so the phases land in a profiler trace on the
+same clock as the device's ops.  The two records join on the scheduler
+step: lifecycle events stamp ``step``, and so does each ``serve.iter``.
+
 Determinism contract: :meth:`TraceRecorder.signature` strips wall-clock
 timestamps, leaving ``(uid, phase, kind, step, args)`` tuples — two runs
 under the same seeded :class:`~repro.serve.faults.FaultPlan` must produce
@@ -22,7 +28,16 @@ import dataclasses
 import json
 import time
 
-__all__ = ["TraceEvent", "TraceRecorder"]
+import jax
+
+__all__ = ["TraceEvent", "TraceRecorder", "span"]
+
+
+def span(name: str, **args):
+    """A profiler span named ``name`` with ``args`` as its stats.  Always
+    on: with no profiler session running it costs the annotation's own
+    ~1 us and records nothing."""
+    return jax.profiler.TraceAnnotation(name, **args)
 
 
 @dataclasses.dataclass
@@ -138,10 +153,6 @@ class TraceRecorder:
 
     # ----------------------------- exports -----------------------------
 
-    def to_json(self):
-        return {"dropped": self.dropped,
-                "events": [dataclasses.asdict(ev) for ev in self.events]}
-
     def to_chrome(self):
         """Chrome trace-event list: pid 1, one pseudo-thread per uid
         (first-seen order), tid 0 for scheduler-scope events."""
@@ -170,10 +181,6 @@ class TraceRecorder:
                 row["s"] = "t"  # thread-scoped instant
             out.append(row)
         return out
-
-    def save(self, path) -> None:
-        with open(path, "w") as f:
-            json.dump(self.to_json(), f)
 
     def save_chrome(self, path) -> None:
         with open(path, "w") as f:

@@ -42,7 +42,7 @@ from repro.core.packed import (PackedDSBPWeight, key_entry_str,
 from repro.core.quantized import PRESETS, pack_weights
 from repro.kvq import is_kv_leaf_path, kv_cache_nbytes, tree_has_packed_kv
 from repro.models import model as M
-from repro.obs import ServeRecorder
+from repro.obs import ServeRecorder, span
 
 __all__ = ["ServeConfig", "Request", "Engine", "pack_weights_int8",
            "pack_tree", "init_packed", "packed_nbytes", "sample_tokens"]
@@ -555,6 +555,11 @@ class Engine:
                     f"kv_block_size {bs} must divide every KV cache length; "
                     f"layer S_c {s_c} (max_len {scfg.max_len}, window "
                     f"{cfg.window}) is not a multiple")
+        # S_c of every KV layer, in stack order: what a decode step gathers
+        self._kv_layer_scs = np.asarray(
+            [MB.cache_len(cfg, k, scfg.max_len)
+             for k in list(cfg.pattern) * cfg.n_units + list(cfg.tail)
+             if MB.KIND_HAS_KV[k]], np.int64)
         s_max = self._kv_scs[-1] if self._kv_scs else 0
         # one table entry spans kv_block_size ring slots of EVERY KV layer
         self._table_width = max(SB.block_span(s_max, bs), 1)
@@ -813,7 +818,8 @@ class Engine:
             logits = faults.corrupt_logits(logits, occ, retry=retry)
         if self._guard is None:
             return logits, []
-        finite = np.asarray(self._finite(jnp.asarray(logits)))
+        with span("serve.guard_wait"):
+            finite = np.asarray(self._finite(jnp.asarray(logits)))
         ctl.stats["guard_checks"] += 1
         bad = [i for i in occ if not finite[i]]
         if bad:
@@ -936,38 +942,49 @@ class Engine:
         with MoE capacity dropping disabled, so each row's score equals
         scoring it alone at batch size 1 (batch invariance,
         tests/test_policy.py) — the contract the eval harness and the
-        policy autotuner rely on.
+        policy autotuner rely on.  Each call is one ``score.call`` span:
+        ``score.prepare`` (padding, host arrays), ``score.run`` (dispatch)
+        and ``score.wait`` (the device sync on the scores).
         """
         cfg, scfg = self.cfg, self.scfg
         if cfg.frontend in ("audio_codebooks", "vlm_patches"):
             raise NotImplementedError(
                 "score_continuations() takes plain token sequences; "
                 f"unsupported for the {cfg.frontend} frontend")
-        seqs = [np.asarray(s, np.int64) for s in sequences]
-        lens = np.asarray([len(s) for s in seqs], np.int32)
-        plens = np.asarray(prompt_lens, np.int32)
-        if np.any(plens >= lens):
-            raise ValueError("every sequence needs >= 1 continuation token")
-        bucket = scfg.prefill_bucket
-        L = max(-(-int(lens.max()) // bucket) * bucket, bucket)
-        toks = np.zeros((len(seqs), L), np.int64)
-        for i, s in enumerate(seqs):
-            toks[i, : lens[i]] = s
-        if self._score_jit is None:
-            def _score(p, toks, plens, slens):
-                logits = M.forward(p, {"tokens": toks}, cfg, no_drop=True)
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-                tgt = toks[:, 1:]
-                lp = jnp.take_along_axis(logp[:, :-1], tgt[..., None],
-                                         axis=-1)[..., 0]
-                pos = jnp.arange(1, toks.shape[1])
-                mask = (pos[None] >= plens[:, None]) & (pos[None] < slens[:, None])
-                return jnp.sum(lp * mask, axis=1)
+        with span("score.call"):
+            with span("score.prepare"):
+                seqs = [np.asarray(s, np.int64) for s in sequences]
+                lens = np.asarray([len(s) for s in seqs], np.int32)
+                plens = np.asarray(prompt_lens, np.int32)
+                if np.any(plens >= lens):
+                    raise ValueError(
+                        "every sequence needs >= 1 continuation token")
+                bucket = scfg.prefill_bucket
+                L = max(-(-int(lens.max()) // bucket) * bucket, bucket)
+                toks = np.zeros((len(seqs), L), np.int64)
+                for i, s in enumerate(seqs):
+                    toks[i, : lens[i]] = s
+                args = (jnp.asarray(toks), jnp.asarray(plens),
+                        jnp.asarray(lens))
+            if self._score_jit is None:
+                def _score(p, toks, plens, slens):
+                    logits = M.forward(p, {"tokens": toks}, cfg, no_drop=True)
+                    with jax.named_scope("lm_head"):
+                        logp = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                                  axis=-1)
+                        lp = jnp.take_along_axis(logp[:, :-1],
+                                                 toks[:, 1:, None],
+                                                 axis=-1)[..., 0]
+                    pos = jnp.arange(1, toks.shape[1])
+                    mask = ((pos[None] >= plens[:, None])
+                            & (pos[None] < slens[:, None]))
+                    return jnp.sum(lp * mask, axis=1)
 
-            self._score_jit = jax.jit(_score)
-        return np.asarray(self._score_jit(
-            self.params, jnp.asarray(toks), jnp.asarray(plens),
-            jnp.asarray(lens)))
+                self._score_jit = jax.jit(_score)
+            with span("score.run"):
+                scores = self._score_jit(self.params, *args)
+            with span("score.wait"):
+                return np.asarray(scores)
 
     # ------------------------------------------------------------------
     # continuous batching
@@ -1029,72 +1046,79 @@ class Engine:
         completed = False
         try:
             while queue or any(s is not None for s in active):
-                live = {active[i].uid:
-                        (active[i],
-                         functools.partial(active.__setitem__, i, None))
-                        for i in range(B) if active[i] is not None}
-                self._drain_control(ctl, queue, live)
-                free = [i for i in range(B) if active[i] is None]
-                if queue and free:
-                    pool, rng = self._admit(pool, queue, free, active, tok,
-                                            pos, ctl, rng)
-                if not any(s is not None for s in active):
-                    ctl.step += 1
-                    continue  # every admitted request finished at token 1
-                stats["decode_steps"] += 1
-                n_occ = sum(s is not None for s in active)
-                stats["occupied_lanes"] += n_occ
-                t_step = time.perf_counter()
-                if self._spec is not None:
-                    pool = self._spec_advance(pool, active, tok, pos, ctl,
-                                              slot_accepted, slot_rounds)
+                with span("serve.iter", step=ctl.step):
+                    with span("serve.control"):
+                        live = {active[i].uid:
+                                (active[i],
+                                 functools.partial(active.__setitem__, i,
+                                                   None))
+                                for i in range(B) if active[i] is not None}
+                        self._drain_control(ctl, queue, live)
+                    free = [i for i in range(B) if active[i] is None]
+                    if queue and free:
+                        with span("serve.admit"):
+                            pool, rng = self._admit(pool, queue, free, active,
+                                                    tok, pos, ctl, rng)
+                    if not any(s is not None for s in active):
+                        ctl.step += 1
+                        continue  # every admitted request finished at token 1
+                    stats["decode_steps"] += 1
+                    n_occ = sum(s is not None for s in active)
+                    stats["occupied_lanes"] += n_occ
+                    t_step = time.perf_counter()
+                    if self._spec is not None:
+                        pool = self._spec_advance(pool, active, tok, pos, ctl,
+                                                  slot_accepted, slot_rounds)
+                        dt = time.perf_counter() - t_step
+                        stats["decode_time_s"] += dt
+                        self.obs.decode_step(ctl.step, n_occ, dt)
+                        ctl.step += 1
+                        continue
+                    occ = [i for i in range(B) if active[i] is not None]
+                    prev = pool if self._guard == "fallback" else None
+                    with span("serve.decode"):
+                        step_toks = {"tokens": jnp.asarray(tok)[:, None]}
+                        logits, pool = self._decode(
+                            self.params, step_toks, pool, jnp.asarray(pos))
+                        last, bad = self._apply_guard(
+                            logits[:, -1], occ, lambda i: active[i].uid, ctl,
+                            cache=pool)
+                        if bad and self._guard == "fallback":
+                            # retry the whole step through the reference quant
+                            # path from the (undonated) pre-step cache — a
+                            # fused-kernel fault clears, a persistent one falls
+                            # to quarantine
+                            stats["fallback_steps"] += 1
+                            logits, pool = self._ref_decode()(
+                                self.params, step_toks, prev,
+                                jnp.asarray(pos))
+                            last, bad = self._apply_guard(
+                                logits[:, -1], occ, lambda i: active[i].uid,
+                                ctl, retry=True, cache=pool)
+                        for i in bad:
+                            self._quarantine(
+                                active[i].uid, ctl,
+                                functools.partial(active.__setitem__, i, None))
+                        nxt, rng = self._sample_next(jnp.asarray(last), rng)
+                    with span("serve.wait"):  # the step's device sync
+                        nxt = np.asarray(nxt)
                     dt = time.perf_counter() - t_step
                     stats["decode_time_s"] += dt
                     self.obs.decode_step(ctl.step, n_occ, dt)
+                    with span("serve.tokens"):
+                        for i in range(B):
+                            r = active[i]
+                            if r is None:
+                                continue  # idle lane: output ignored
+                            pos[i] += 1
+                            t = int(nxt[i])
+                            ctl.out[r.uid].append(t)
+                            tok[i] = t
+                            stats["decode_tokens"] += 1
+                            if self._done(t, ctl.out[r.uid], r):
+                                active[i] = None  # freed for admission
+                                self._finish(ctl, r.uid)
                     ctl.step += 1
-                    continue
-                occ = [i for i in range(B) if active[i] is not None]
-                prev = pool if self._guard == "fallback" else None
-                logits, pool = self._decode(
-                    self.params, {"tokens": jnp.asarray(tok)[:, None]}, pool,
-                    jnp.asarray(pos),
-                )
-                last, bad = self._apply_guard(
-                    logits[:, -1], occ, lambda i: active[i].uid, ctl,
-                    cache=pool)
-                if bad and self._guard == "fallback":
-                    # retry the whole step through the reference quant path
-                    # from the (undonated) pre-step cache — a fused-kernel
-                    # fault clears, a persistent one falls to quarantine
-                    stats["fallback_steps"] += 1
-                    logits, pool = self._ref_decode()(
-                        self.params, {"tokens": jnp.asarray(tok)[:, None]},
-                        prev, jnp.asarray(pos))
-                    last, bad = self._apply_guard(
-                        logits[:, -1], occ, lambda i: active[i].uid, ctl,
-                        retry=True, cache=pool)
-                for i in bad:
-                    self._quarantine(
-                        active[i].uid, ctl,
-                        functools.partial(active.__setitem__, i, None))
-                nxt, rng = self._sample_next(jnp.asarray(last), rng)
-                nxt = np.asarray(nxt)  # device sync: step wall cost lands here
-                dt = time.perf_counter() - t_step
-                stats["decode_time_s"] += dt
-                self.obs.decode_step(ctl.step, n_occ, dt)
-                for i in range(B):
-                    r = active[i]
-                    if r is None:
-                        continue  # idle lane: output ignored, slot unchanged
-                    pos[i] += 1
-                    t = int(nxt[i])
-                    ctl.out[r.uid].append(t)
-                    tok[i] = t
-                    stats["decode_tokens"] += 1
-                    if self._done(t, ctl.out[r.uid], r):
-                        active[i] = None  # freed; next admission reuses it
-                        self._finish(ctl, r.uid)
-                ctl.step += 1
             completed = True
         finally:
             # last_stats lands even when an exception unwinds mid-loop —
@@ -1228,7 +1252,8 @@ class Engine:
             logits[:, -1], list(range(len(group))),
             lambda j: group[j].uid, ctl, inject=False)
         first, rng = self._sample_next(jnp.asarray(last), rng)
-        first = np.asarray(first)
+        with span("serve.admit_wait"):
+            first = np.asarray(first)
         stats["admissions"] += len(group)
         stats["prefill_tokens"] += int(lens.sum())
         badset = set(badrows)
@@ -1283,13 +1308,13 @@ class Engine:
             # a reservation that exceeds the whole pool can NEVER succeed:
             # fail fast instead of deadlocking the admission loop
             for r in queue:
-                span = SB.block_span(
+                blocks = SB.block_span(
                     min(len(r.tokens) + r.max_new_tokens + headroom,
                         self._kv_scs[-1]), bs)
-                if span > self.kv_blocks - 1:
+                if blocks > self.kv_blocks - 1:
                     raise SB.BlockError(
                         f"request {r.uid!r} cannot be admitted even with an "
-                        f"idle pool: its reservation ({span} blocks) exceeds "
+                        f"idle pool: its reservation ({blocks} blocks) exceeds "
                         f"kv_blocks={self.kv_blocks} ({self.kv_blocks - 1} "
                         f"usable)")
         self.obs.serve_start("paged", [(r.uid, len(r.tokens))
@@ -1318,6 +1343,7 @@ class Engine:
         for path, leaf in jax.tree_util.tree_leaves_with_path(cache):
             if is_kv_leaf_path(path):
                 blk_bytes += (leaf.size * leaf.dtype.itemsize) // nb_pool
+        kv_row_bytes = blk_bytes // max(bs * len(self._kv_layer_scs), 1)
         tables = np.zeros((B, self._table_width), np.int32)
         lanes: list[dict | None] = [None] * B
         tok = np.zeros(B, np.int64)
@@ -1332,6 +1358,9 @@ class Engine:
                  "stalled_decode_steps": 0,
                  "interleaved_decode_steps": 0, "max_concurrent": 0,
                  "shared_blocks_peak": 0, "admission_blocked": 0,
+                 # KV rows the decode steps gathered (every lane's whole
+                 # table, per KV layer) and the rows decoding lanes held
+                 "kv_rows_gathered": 0, "kv_rows_live": 0,
                  **self._robust_stats()}
         ctl = _ServeControl(stats=stats, out={},
                             status={r.uid: "queued" for r in queue},
@@ -1343,116 +1372,87 @@ class Engine:
         completed = False
         try:
             while queue or any(l is not None for l in lanes):
-                live = {lanes[i]["req"].uid:
-                        (lanes[i]["req"],
-                         functools.partial(self._release_lane, i, lanes,
-                                           tables, alloc))
-                        for i in range(B) if lanes[i] is not None}
-                self._drain_control(ctl, queue, live)
-                free = [i for i in range(B) if lanes[i] is None]
-                if queue and free:
-                    cache, rng = self._admit_paged(
-                        cache, queue, free, lanes, tables, alloc, prefix,
-                        tok, pos, ctl, rng)
-                dec = [i for i, l in enumerate(lanes)
-                       if l is not None and l["phase"] == "decode"]
-                chk = [i for i, l in enumerate(lanes)
-                       if l is not None and l["phase"] == "chunk"]
-                if not dec and not chk:
-                    if queue:
-                        # blocked admission with an idle pool: transient
-                        # under fault injection / prefix evictions, but a
-                        # pathological plan must terminate, not spin
-                        idle_spins += 1
-                        if idle_spins > 4 * self.kv_blocks + 64:
-                            raise SB.BlockError(
-                                f"scheduler made no progress for "
-                                f"{idle_spins} iterations with an idle "
-                                f"pool: request {queue[0].uid!r} cannot "
-                                f"reserve its blocks")
-                    ctl.step += 1
-                    continue  # every admitted request finished at token 1
-                idle_spins = 0
-                stats["max_concurrent"] = max(stats["max_concurrent"],
-                                              len(dec) + len(chk))
-                if alloc is not None:
-                    stats["shared_blocks_peak"] = max(
-                        stats["shared_blocks_peak"], alloc.shared_blocks())
-                    self.obs.pool_sample(ctl.step, alloc, prefix)
-                if dec:
-                    t_step = time.perf_counter()
-                    # COW before the step: every ring slot this round writes
-                    # (spec rounds write up to spec_k+1) must be exclusively
-                    # owned — shared prefix blocks split here.  Under pool
-                    # pressure this may preempt a victim lane (possibly one
-                    # in dec): re-derive the decode set afterwards.
-                    cache = self._cow_writable(
-                        cache, tables, alloc, prefix,
-                        [(i, int(pos[i]), 1 + headroom) for i in dec], stats,
-                        lanes=lanes, queue=queue, ctl=ctl)
-                    dec = [i for i in dec if lanes[i] is not None]
-                    chk = [i for i in chk if lanes[i] is not None]
-                if dec:
-                    stats["decode_steps"] += 1
-                    stats["occupied_lanes"] += len(dec) + len(chk)
+                with span("serve.iter", step=ctl.step):
+                    with span("serve.control"):
+                        live = {lanes[i]["req"].uid:
+                                (lanes[i]["req"],
+                                 functools.partial(self._release_lane, i,
+                                                   lanes, tables, alloc))
+                                for i in range(B) if lanes[i] is not None}
+                        self._drain_control(ctl, queue, live)
+                    free = [i for i in range(B) if lanes[i] is None]
+                    if queue and free:
+                        with span("serve.admit"):
+                            cache, rng = self._admit_paged(
+                                cache, queue, free, lanes, tables, alloc,
+                                prefix, tok, pos, ctl, rng)
+                    dec = [i for i, l in enumerate(lanes)
+                           if l is not None and l["phase"] == "decode"]
+                    chk = [i for i, l in enumerate(lanes)
+                           if l is not None and l["phase"] == "chunk"]
+                    if not dec and not chk:
+                        if queue:
+                            # blocked admission with an idle pool: transient
+                            # under fault injection / prefix evictions, but
+                            # a pathological plan must terminate, not spin
+                            idle_spins += 1
+                            if idle_spins > 4 * self.kv_blocks + 64:
+                                raise SB.BlockError(
+                                    f"scheduler made no progress for "
+                                    f"{idle_spins} iterations with an idle "
+                                    f"pool: request {queue[0].uid!r} cannot "
+                                    f"reserve its blocks")
+                        ctl.step += 1
+                        continue  # every admission finished at token 1
+                    idle_spins = 0
+                    stats["max_concurrent"] = max(stats["max_concurrent"],
+                                                  len(dec) + len(chk))
+                    if alloc is not None:
+                        stats["shared_blocks_peak"] = max(
+                            stats["shared_blocks_peak"],
+                            alloc.shared_blocks())
+                        self.obs.pool_sample(ctl.step, alloc, prefix)
+                    if dec:
+                        t_step = time.perf_counter()
+                        # COW before the step: every ring slot this round
+                        # writes (spec rounds write up to spec_k+1) must be
+                        # exclusively owned — shared prefix blocks split
+                        # here.  Under pool pressure this may preempt a
+                        # victim lane (possibly one in dec): re-derive the
+                        # decode set afterwards.
+                        with span("serve.cow"):
+                            cache = self._cow_writable(
+                                cache, tables, alloc, prefix,
+                                [(i, int(pos[i]), 1 + headroom) for i in dec],
+                                stats, lanes=lanes, queue=queue, ctl=ctl)
+                        dec = [i for i in dec if lanes[i] is not None]
+                        chk = [i for i in chk if lanes[i] is not None]
+                    if dec:
+                        stats["decode_steps"] += 1
+                        stats["occupied_lanes"] += len(dec) + len(chk)
+                        if chk:
+                            stats["interleaved_decode_steps"] += 1
+                        if self._spec_paged is not None:
+                            cache = self._spec_advance_paged(
+                                cache, lanes, tables, alloc, prefix, dec, tok,
+                                pos, ctl)
+                        else:
+                            cache, rng = self._decode_advance_paged(
+                                cache, lanes, tables, alloc, dec, tok, pos,
+                                ctl, rng, kv_row_bytes)
+                        dt = time.perf_counter() - t_step
+                        stats["decode_time_s"] += dt
+                        self.obs.decode_step(ctl.step, len(dec) + len(chk),
+                                             dt)
                     if chk:
-                        stats["interleaved_decode_steps"] += 1
-                    if self._spec_paged is not None:
-                        cache = self._spec_advance_paged(
-                            cache, lanes, tables, alloc, prefix, dec, tok,
-                            pos, ctl)
-                    else:
-                        live_m = np.zeros(B, np.int32)
-                        live_m[dec] = 1  # idle/chunk lanes: write_len 0
-                        step_toks = {"tokens": jnp.asarray(tok)[:, None]}
-                        prev = cache if self._guard == "fallback" else None
-                        logits, cache = self._decode_paged(
-                            self.params, step_toks, cache,
-                            jnp.asarray(tables), jnp.asarray(pos),
-                            jnp.asarray(live_m))
-                        last, bad = self._apply_guard(
-                            logits[:, -1], dec,
-                            lambda i: lanes[i]["req"].uid, ctl, cache=cache)
-                        if bad and self._guard == "fallback":
-                            stats["fallback_steps"] += 1
-                            logits, cache = self._ref_decode_paged()(
-                                self.params, step_toks, prev,
-                                jnp.asarray(tables), jnp.asarray(pos),
-                                jnp.asarray(live_m))
-                            last, bad = self._apply_guard(
-                                logits[:, -1], dec,
-                                lambda i: lanes[i]["req"].uid, ctl,
-                                retry=True, cache=cache)
-                        for i in bad:
-                            self._quarantine(
-                                lanes[i]["req"].uid, ctl,
-                                functools.partial(self._release_lane, i,
-                                                  lanes, tables, alloc))
-                        nxt, rng = self._sample_next(jnp.asarray(last), rng)
-                        nxt = np.asarray(nxt)
-                        for i in dec:
-                            if lanes[i] is None:
-                                continue  # quarantined this step
-                            r = lanes[i]["req"]
-                            pos[i] += 1
-                            t = int(nxt[i])
-                            ctl.out[r.uid].append(t)
-                            tok[i] = t
-                            stats["decode_tokens"] += 1
-                            if self._done(t, ctl.out[r.uid], r):
-                                self._release_lane(i, lanes, tables, alloc)
-                                self._finish(ctl, r.uid)
-                    dt = time.perf_counter() - t_step
-                    stats["decode_time_s"] += dt
-                    self.obs.decode_step(ctl.step, len(dec) + len(chk), dt)
-                if chk:
-                    cache, rng = self._chunk_step(
-                        cache, lanes, tables, alloc, prefix, queue, chk,
-                        tok, pos, ctl, rng)
-                if check and alloc is not None:
-                    FA.check_invariants(alloc, tables, lanes, prefix)
-                    stats["invariant_checks"] += 1
-                ctl.step += 1
+                        with span("serve.chunk"):
+                            cache, rng = self._chunk_step(
+                                cache, lanes, tables, alloc, prefix, queue,
+                                chk, tok, pos, ctl, rng)
+                    if check and alloc is not None:
+                        FA.check_invariants(alloc, tables, lanes, prefix)
+                        stats["invariant_checks"] += 1
+                    ctl.step += 1
             completed = True
         finally:
             # conservation on ANY exit: every live lane's block references
@@ -1624,7 +1624,8 @@ class Engine:
                 logits[:, -1], list(range(len(group))),
                 lambda j: group[j][1].uid, ctl, inject=False)
             first, rng = self._sample_next(jnp.asarray(last), rng)
-            first = np.asarray(first)
+            with span("serve.admit_wait"):
+                first = np.asarray(first)
             stats["admissions"] += len(group)
             stats["prefill_tokens"] += int(lens.sum())
             badset = set(badrows)
@@ -1782,8 +1783,9 @@ class Engine:
         """Advance every chunk lane by one <=chunk_T-token slice through the
         verify path (teacher-forced forward over known prompt tokens) and
         commit keep=n_valid — the SAME cache-write helper spec rollback
-        uses.  The final chunk's last logit samples the first token and the
-        lane flips to 'decode'."""
+        uses.  The final chunk's last logit samples the first token (its
+        device sync is ``serve.chunk_wait``) and the lane flips to
+        'decode'."""
         scfg = self.scfg
         stats, out = ctl.stats, ctl.out
         B, T = self.lanes, self._chunk_T
@@ -1828,7 +1830,8 @@ class Engine:
                 sel, list(range(len(fin))),
                 lambda j: lanes[fin[j][0]]["req"].uid, ctl, inject=False)
             first, rng = self._sample_next(jnp.asarray(sel), rng)
-            first = np.asarray(first)
+            with span("serve.chunk_wait"):
+                first = np.asarray(first)
             badset = set(badrows)
             for j, (i, _) in enumerate(fin):
                 r = lanes[i]["req"]
@@ -1856,6 +1859,62 @@ class Engine:
                 lanes[i] = {"req": r, "phase": "decode", "done0": done0}
                 tok[i] = t
                 pos[i] = len(r.tokens)
+        return cache, rng
+
+    def _decode_advance_paged(self, cache, lanes, tables, alloc, dec, tok,
+                              pos, ctl, rng, kv_row_bytes):
+        """One decode step over every decode lane: its dispatch
+        (``serve.decode``), the device sync on the sampled tokens
+        (``serve.wait``) and the per-lane bookkeeping (``serve.tokens``).
+        Returns (cache, advanced rng)."""
+        stats = ctl.stats
+        live_m = np.zeros(self.lanes, np.int32)
+        live_m[dec] = 1  # idle/chunk lanes: write_len 0
+        scs = self._kv_layer_scs
+        gathered = self.lanes * int(scs.sum())
+        live_rows = int(np.minimum(pos[dec][None, :] + 1, scs[:, None]).sum())
+        stats["kv_rows_gathered"] += gathered
+        stats["kv_rows_live"] += live_rows
+        self.obs.kv_read(ctl.step, gathered, live_rows, kv_row_bytes)
+        with span("serve.decode", kv_rows_gathered=gathered,
+                  kv_rows_live=live_rows):
+            step_toks = {"tokens": jnp.asarray(tok)[:, None]}
+            prev = cache if self._guard == "fallback" else None
+            logits, cache = self._decode_paged(
+                self.params, step_toks, cache, jnp.asarray(tables),
+                jnp.asarray(pos), jnp.asarray(live_m))
+            last, bad = self._apply_guard(
+                logits[:, -1], dec, lambda i: lanes[i]["req"].uid, ctl,
+                cache=cache)
+            if bad and self._guard == "fallback":
+                stats["fallback_steps"] += 1
+                logits, cache = self._ref_decode_paged()(
+                    self.params, step_toks, prev, jnp.asarray(tables),
+                    jnp.asarray(pos), jnp.asarray(live_m))
+                last, bad = self._apply_guard(
+                    logits[:, -1], dec, lambda i: lanes[i]["req"].uid, ctl,
+                    retry=True, cache=cache)
+            for i in bad:
+                self._quarantine(
+                    lanes[i]["req"].uid, ctl,
+                    functools.partial(self._release_lane, i, lanes, tables,
+                                      alloc))
+            nxt, rng = self._sample_next(jnp.asarray(last), rng)
+        with span("serve.wait"):
+            nxt = np.asarray(nxt)
+        with span("serve.tokens"):
+            for i in dec:
+                if lanes[i] is None:
+                    continue  # quarantined this step
+                r = lanes[i]["req"]
+                pos[i] += 1
+                t = int(nxt[i])
+                ctl.out[r.uid].append(t)
+                tok[i] = t
+                stats["decode_tokens"] += 1
+                if self._done(t, ctl.out[r.uid], r):
+                    self._release_lane(i, lanes, tables, alloc)
+                    self._finish(ctl, r.uid)
         return cache, rng
 
     def _spec_advance_paged(self, cache, lanes, tables, alloc, prefix, dec,
@@ -1960,7 +2019,8 @@ class Engine:
         return dataclasses.replace(r, tokens=toks)
 
     def _sample(self, logits, rng):
-        return sample_tokens(logits, self.cfg, self.scfg.temperature, rng)
+        with jax.named_scope("sample"):
+            return sample_tokens(logits, self.cfg, self.scfg.temperature, rng)
 
     def _sample_next(self, logits, rng):
         """Split-then-sample: every draw gets a fresh subkey (never a key

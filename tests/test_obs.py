@@ -3,13 +3,16 @@
 Core contracts: the recorder is deterministic under a seeded
 :class:`~repro.serve.faults.FaultPlan` (same plan => same event sequence
 modulo timestamps), histogram bucket math follows Prometheus ``le``
-semantics, both exports round-trip, ``Engine.last_stats`` stays
-backwards-compatible with ``observe=True``, and
-:func:`~repro.policy.reprice_from_telemetry` widens exactly the layers the
-guard telemetry implicates.
+semantics, the snapshot is JSON-able, ``Engine.last_stats`` stays
+backwards-compatible with ``observe=True``, the schedulers' profiler spans
+nest as DESIGN.md §15 lists them, the model's programs carry their named
+scopes, and :func:`~repro.policy.reprice_from_telemetry` widens exactly the
+layers the guard telemetry implicates.
 """
 import dataclasses
+import glob
 import json
+import re
 
 import numpy as np
 import jax
@@ -94,6 +97,8 @@ def test_counter_rejects_negative_and_kind_conflict():
 
 
 def test_registry_snapshot_roundtrip_and_prometheus():
+    """The snapshot (the registry's one export) holds every family with
+    its kind, help and labelled series, and survives a JSON round trip."""
     reg = MetricsRegistry()
     reg.counter("serve_requests_total", status="ok").inc(2)
     reg.counter("serve_requests_total", status="cancelled").inc()
@@ -102,18 +107,20 @@ def test_registry_snapshot_roundtrip_and_prometheus():
     h.observe(0.05)
     h.observe(2.0)
     snap = reg.snapshot()
-    json.dumps(snap)  # JSON-able
-    back = MetricsRegistry.from_snapshot(snap)
-    assert back.snapshot() == snap
-    assert back.value("serve_requests_total", status="ok") == 2
-    text = reg.to_prometheus()
-    assert "# TYPE serve_requests_total counter" in text
-    assert 'serve_requests_total{status="ok"} 2' in text
-    assert 'serve_ttft_seconds_bucket{le="+Inf"} 2' in text
-    assert 'serve_ttft_seconds_bucket{le="0.1"} 1' in text
-    assert "serve_ttft_seconds_count 2" in text
-    # round-tripped registry renders the identical exposition
-    assert back.to_prometheus() == text
+    assert json.loads(json.dumps(snap)) == snap
+    fams = snap["families"]
+    assert list(fams) == sorted(fams)
+    req = fams["serve_requests_total"]
+    assert req["kind"] == "counter"
+    assert req["series"] == [{"labels": {"status": "cancelled"}, "value": 1},
+                             {"labels": {"status": "ok"}, "value": 2}]
+    assert fams["serve_decode_tps"]["series"] == [{"labels": {},
+                                                   "value": 12.5}]
+    ttft = fams["serve_ttft_seconds"]
+    assert ttft["kind"] == "histogram" and ttft["help"] == "ttft"
+    assert ttft["series"] == [{"labels": {}, "buckets": [0.1, 1.0],
+                               "counts": [1, 0, 1], "sum": 2.05,
+                               "count": 2}]
 
 
 # ---------------------------------------------------------------------------
@@ -146,7 +153,6 @@ def test_trace_caps_and_counts_drops():
     for i in range(5):
         tr.instant("a", "tick", i)
     assert len(tr.events) == 3 and tr.dropped == 2
-    assert tr.to_json()["dropped"] == 2
 
 
 def test_trace_chrome_export_structure():
@@ -237,6 +243,140 @@ def test_guard_trip_telemetry_under_nan_injection(fparams):
     trips = [e for e in obs.trace.events if e.phase == "guard-trip"]
     assert trips and all(e.args["entries"] == "unattributed" for e in trips)
     assert obs.complete_spans(eng.last_stats["request_status"])
+
+
+# ---------------------------------------------------------------------------
+# profiler spans and named scopes (the device trace's clock)
+# ---------------------------------------------------------------------------
+
+# program span -> the program spans that open directly inside it
+_SPAN_CHILDREN = {
+    None: {"serve.iter", "score.call"},
+    "serve.iter": {"serve.control", "serve.admit", "serve.cow",
+                   "serve.decode", "serve.wait", "serve.tokens",
+                   "serve.chunk"},
+    "serve.admit": {"serve.admit_wait", "serve.guard_wait"},
+    "serve.decode": {"serve.guard_wait"},
+    "serve.chunk": {"serve.chunk_wait", "serve.guard_wait"},
+    "score.call": {"score.prepare", "score.run", "score.wait"},
+}
+
+
+def _traced_spans(log_dir, run):
+    """(name, stats, parent name) of every program span ``run`` opens
+    under a profiler session, in start order."""
+    from jax.profiler import ProfileData
+
+    with jax.profiler.trace(str(log_dir)):
+        run()
+    path = glob.glob(str(log_dir / "**" / "*.xplane.pb"), recursive=True)[0]
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            evs = sorted(((ev.start_ns, ev.end_ns, ev.name, dict(ev.stats))
+                          for ev in line.events
+                          if ev.name.startswith(("serve.", "score."))),
+                         key=lambda e: (e[0], -e[1]))
+            stack = []
+            for s, e, name, stats in evs:
+                while stack and stack[-1][0] <= s:
+                    stack.pop()
+                out.append((name, stats, stack[-1][1] if stack else None))
+                stack.append((e, name))
+    return out
+
+
+@pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
+def test_serve_spans_nest_one_iter_per_step(fparams, tmp_path, paged):
+    cfg = _cfg()
+    scfg = (_paged_scfg(chunk_prefill_tokens=8, prefill_bucket=4,
+                        numeric_guard="quarantine") if paged
+            else ServeConfig(max_len=32, batch_size=2,
+                             numeric_guard="quarantine"))
+    eng = Engine(fparams, cfg, scfg)
+    reqs = _reqs(cfg, [5, 12, 9])
+    eng.serve([dataclasses.replace(r) for r in reqs])  # compile outside
+    spans = _traced_spans(
+        tmp_path, lambda: eng.serve([dataclasses.replace(r) for r in reqs]))
+    for name, _, parent in spans:
+        assert name in _SPAN_CHILDREN[parent], (parent, name)
+    steps = [st["step"] for name, st, _ in spans if name == "serve.iter"]
+    assert steps == list(range(len(steps))) and steps
+    seen = {name for name, _, _ in spans}
+    assert {"serve.control", "serve.admit", "serve.admit_wait",
+            "serve.decode", "serve.guard_wait", "serve.wait",
+            "serve.tokens"} <= seen
+    if paged:
+        assert {"serve.cow", "serve.chunk", "serve.chunk_wait"} <= seen
+        # the decode span carries the step's KV read counts
+        rows = [st for name, st, _ in spans if name == "serve.decode"]
+        assert sum(st["kv_rows_gathered"] for st in rows) == \
+            eng.last_stats["kv_rows_gathered"] > 0
+        assert sum(st["kv_rows_live"] for st in rows) == \
+            eng.last_stats["kv_rows_live"]
+
+
+def test_score_spans_nest(fparams, tmp_path):
+    cfg = _cfg()
+    eng = Engine(fparams, cfg, ServeConfig(max_len=32, batch_size=2))
+    seqs, plens = [np.arange(9), np.arange(5)], [4, 2]
+    eng.score_continuations(seqs, plens)
+    spans = _traced_spans(tmp_path,
+                          lambda: eng.score_continuations(seqs, plens))
+    assert [(n, p) for n, _, p in spans] == [
+        ("score.call", None), ("score.prepare", "score.call"),
+        ("score.run", "score.call"), ("score.wait", "score.call")]
+
+
+def test_kv_read_counts_paged_decode_rows(fparams):
+    """Rows gathered: every lane's whole table per KV layer; rows live:
+    position + 1 per decoding lane and KV layer.  The recorder's counters
+    and last_stats agree."""
+    cfg = _cfg()
+    eng = Engine(fparams, cfg, _paged_scfg(observe=True))
+    eng.serve(_reqs(cfg, [5, 9]))
+    st = eng.last_stats
+    per_step = eng.lanes * 32 * cfg.n_layers   # max_len 32, full attention
+    assert st["kv_rows_gathered"] == st["decode_steps"] * per_step
+    # two lanes admitted at 5 and 9 decode 7 steps each (8 tokens, the
+    # first from prefill): positions 5..11 and 9..15
+    assert st["kv_rows_live"] == cfg.n_layers * (sum(range(6, 13))
+                                                 + sum(range(10, 17)))
+    m = eng.obs.metrics
+    assert m.value("serve_kv_rows_gathered_total") == st["kv_rows_gathered"]
+    assert m.value("serve_kv_rows_live_total") == st["kv_rows_live"]
+    assert m.value("serve_kv_bytes_gathered_total") > 0
+
+
+def test_engine_serves_under_a_bare_hook_object(fparams):
+    """The benchmark swaps ``engine.obs`` for an object whose hooks are
+    no-op lambdas; the spans never go through ``engine.obs``."""
+    class Bare:
+        def __getattr__(self, name):
+            return lambda *a, **k: None
+
+    cfg = _cfg()
+    reqs = _reqs(cfg, [5, 9])
+    ref = Engine(fparams, cfg, _paged_scfg()).serve(
+        [dataclasses.replace(r) for r in reqs])
+    eng = Engine(fparams, cfg, _paged_scfg())
+    eng.obs = Bare()
+    out = eng.serve([dataclasses.replace(r) for r in reqs])
+    assert all(np.array_equal(out[u], ref[u]) for u in ref)
+
+
+def test_decode_program_carries_named_scopes(fparams):
+    cfg = _cfg()
+    eng = Engine(fparams, cfg, _paged_scfg())
+    b = eng.lanes
+    cache = M.init_paged_cache(cfg, b, eng.kv_blocks, 4, kv=eng.kv_spec)
+    text = eng._decode_paged.lower(
+        eng.params, {"tokens": jnp.zeros((b, 1), jnp.int32)}, cache,
+        jnp.zeros((b, eng._table_width), jnp.int32),
+        jnp.zeros(b, jnp.int32), jnp.ones(b, jnp.int32)).compile().as_text()
+    segments = {seg for path in re.findall(r'op_name="([^"]*)"', text)
+                for seg in path.split("/")}
+    assert {"attention", "kv_gather", "qkv", "lm_head"} <= segments
 
 
 # ---------------------------------------------------------------------------
