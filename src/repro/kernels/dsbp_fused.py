@@ -22,6 +22,13 @@ bit is ever rounded before the MXU dot.  The kernel is bit-exact vs
 reduction fits one block (tests/test_fused.py); on the MXU the dot runs at
 ``Precision.HIGHEST``, so the integer products stay exact there too.
 
+The input path runs once per (row block, K tile), not once per output
+tile: the grid visits a row block's output tiles in order, the first one
+aligns each K tile of the block and keeps the scale-folded result in a
+``(K/bk, bm, bk)`` f32 VMEM scratch, and the others read it back, so ``x``
+is read from HBM once per row block.  The stored values are the ones the
+dot would have received, so every output tile's result is unchanged.
+
 The weight operands are consumed in the container's stored kernel layout
 (``PackedDSBPWeight.ka (K', N)`` int8 / ``.kscale (ng, N)``), so the
 serving path performs zero per-call relayout.
@@ -34,6 +41,7 @@ import math
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.dsbp import DSBPConfig
 
@@ -41,30 +49,39 @@ from . import backend
 from .fp8_quant_align import pick_bk, quant_align_tile
 
 GROUP = 64
+# default scoped VMEM of a v5e TensorCore; a longer reduction's aligned-input
+# scratch (bm * K f32: 16 MiB at K = 32768) raises the limit by its own size
+_SCOPED_VMEM = 16 * 2**20
 
 __all__ = ["dsbp_fused_kernel_call", "dsbp_fused_sharded_call", "GROUP"]
 
 
-def _kernel(x_ref, ts_ref, aw_ref, sw_ref, tw_ref, o_ref, *,
+def _kernel(x_ref, ts_ref, aw_ref, sw_ref, tw_ref, o_ref, ae_ref, *,
             cfg: DSBPConfig):
-    kk = pl.program_id(2)
+    j, kk = pl.program_id(1), pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
         o_ref[...] = jnp.zeros_like(o_ref)
 
-    ts = ts_ref[0, 0]  # per-tensor input scale (power of two)
-    # ---- on-the-fly input path, entirely in VMEM; scales come per lane ----
-    a, s, _bits = quant_align_tile(x_ref[...].astype(jnp.float32) * ts, cfg)
-    # ---- fold the pow2 tensor scales into the pow2 group scales (exact)
-    # and run the folded MXU dot (dsbp_matmul._kernel_folded) ----
-    ae = a * (s / ts)
+    # ---- on-the-fly input path, entirely in VMEM, once per (row block,
+    # K tile): the first output tile aligns the input and keeps it in
+    # ``ae_ref``; the row block's other output tiles read it back ----
+    @pl.when(j == 0)
+    def _input_path():
+        ts = ts_ref[0, 0]  # per-tensor input scale (power of two)
+        a, s, _bits = quant_align_tile(
+            x_ref[...].astype(jnp.float32) * ts, cfg)
+        # fold the pow2 tensor scale into the pow2 group scales (exact)
+        ae_ref[kk] = a * (s / ts)
+
+    # ---- the folded MXU dot (dsbp_matmul._kernel_folded) ----
     gpb, _, bn = sw_ref.shape  # (groups, 1, bn): one scale row per group
     we = (
         aw_ref[...].astype(jnp.float32).reshape(gpb, GROUP, bn)
         * (sw_ref[...] / tw_ref[...])
     ).reshape(gpb * GROUP, bn)
-    o_ref[...] += jnp.dot(ae, we, preferred_element_type=jnp.float32,
+    o_ref[...] += jnp.dot(ae_ref[kk], we, preferred_element_type=jnp.float32,
                           precision=jax.lax.Precision.HIGHEST)
 
 
@@ -84,7 +101,8 @@ def dsbp_fused_kernel_call(
     bk: int | None = None,
     interpret: bool | None = None,
 ):
-    """One-pass DSBP GEMM over a (M, N, K) grid.
+    """One-pass DSBP GEMM over a (M, N, K) grid; the input path runs at
+    the first output tile of each row block and the rest reuse it.
 
     x  (M, K')  f32 raw activations (K' group-padded, NOT pre-scaled)
     ts ()/(1,1) f32 power-of-two per-tensor input scale
@@ -119,12 +137,16 @@ def dsbp_fused_kernel_call(
         x = jnp.pad(x, ((0, pad_m), (0, 0)))
     mp = m + pad_m
     ts = jnp.asarray(ts, jnp.float32).reshape(1, 1)
-    gpb = bk // GROUP
+    gpb, nk = bk // GROUP, k // bk
+    ae_bytes = 4 * bm * k  # the aligned-input scratch below
     y = pl.pallas_call(
         functools.partial(_kernel, cfg=cfg),
-        grid=(mp // bm, n // bn, k // bk),
+        grid=(mp // bm, n // bn, nk),
         in_specs=[
-            pl.BlockSpec((bm, bk), lambda i, j, kk: (i, kk)),
+            # x is read only while j == 0; after that its block index stays
+            # on the last K tile, so no output tile past the first fetches it
+            pl.BlockSpec((bm, bk),
+                         lambda i, j, kk: (i, jnp.where(j == 0, kk, nk - 1))),
             pl.BlockSpec((1, 1), lambda i, j, kk: (0, 0)),
             pl.BlockSpec((bk, bn), lambda i, j, kk: (kk, j)),
             # (ng, 1, N): a (gpb, 1, bn) block is legal for any gpb, where
@@ -134,6 +156,13 @@ def dsbp_fused_kernel_call(
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, kk: (i, j)),
         out_shape=jax.ShapeDtypeStruct((mp, n), jnp.float32),
+        # the row block's scale-folded aligned input, one slot per K tile
+        scratch_shapes=[pltpu.VMEM((nk, bm, bk), jnp.float32)],
+        # output tiles past the first read what the first one stored
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary"),
+            vmem_limit_bytes=(_SCOPED_VMEM + ae_bytes
+                              if ae_bytes > _SCOPED_VMEM // 2 else None)),
         interpret=interpret,
     )(x, ts, aw, sw.reshape(ng, 1, n), tw)
     return y[:m] if pad_m else y

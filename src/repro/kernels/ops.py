@@ -149,7 +149,8 @@ def dsbp_matmul_fused(
     """Fused one-pass DSBP GEMM: x (..., K) @ packed(K, N) -> (..., N) f32.
 
     The serving hot path (DESIGN.md §8): quantize + predict + align + MAC
-    run in ONE Pallas kernel per output tile — the aligned-int intermediate
+    run in ONE Pallas kernel, the input path once per row block and K tile
+    and reused by every output tile — the aligned-int intermediate
     and its scales never touch HBM, the pow2 tensor scales of both operands
     fold into the group scales inside the kernel (no pre-multiply / final
     division pass), and the weight operands are the container's stored

@@ -23,8 +23,11 @@ from repro.models import blocks
 from repro.models.layers import Quant
 from repro.serve.engine import pack_tree
 
-# yi-9b's projection (K, N): wq/wo, wk/wv, w1/w3, w2
-YI_KN = [(4096, 4096), (4096, 512), (4096, 11008), (11008, 4096)]
+# yi-9b's projection (K, N): wq/wo, wk/wv, w1/w3, w2, then the serving
+# path's fused qkv and mlp_in (w1 | w3), whose 20 and 86 output tiles
+# reuse the aligned input of their row block
+YI_KN = [(4096, 4096), (4096, 512), (4096, 11008), (11008, 4096),
+         (4096, 5120), (4096, 22016)]
 
 
 @pytest.fixture(scope="module")
@@ -47,9 +50,7 @@ def _abstract(tree, sharding):
         tree)
 
 
-@pytest.mark.parametrize("m", [8, 256])
-@pytest.mark.parametrize("k,n", YI_KN)
-def test_fused_gemm_compiles_for_v5e(one_chip, m, k, n):
+def _compile_fused_gemm(sharding, m, k, n):
     cfg = PRESETS["precise"].input_cfg
     args = _abstract([
         jax.ShapeDtypeStruct((m, k), jnp.float32),   # x
@@ -57,9 +58,23 @@ def test_fused_gemm_compiles_for_v5e(one_chip, m, k, n):
         jax.ShapeDtypeStruct((k, n), jnp.int8),      # ka
         jax.ShapeDtypeStruct((k // 64, n), jnp.float32),  # kscale
         jax.ShapeDtypeStruct((1, n), jnp.float32),   # tscale
-    ], one_chip)
-    compiled = jax.jit(lambda *a: dsbp_fused_kernel_call(
+    ], sharding)
+    return jax.jit(lambda *a: dsbp_fused_kernel_call(
         *a, cfg, interpret=False)).lower(*args).compile()
+
+
+@pytest.mark.parametrize("m", [8, 256, 128])  # decode, two blocks, verify
+@pytest.mark.parametrize("k,n", YI_KN)
+def test_fused_gemm_compiles_for_v5e(one_chip, m, k, n):
+    compiled = _compile_fused_gemm(one_chip, m, k, n)
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_fused_gemm_long_reduction_compiles_for_v5e(one_chip):
+    """grok-1's mlp_out reduction (K = 32768) at a verify row block: the
+    row block's aligned input alone (16 MiB of f32) fills the default
+    scoped VMEM, so the kernel raises its limit by that much."""
+    compiled = _compile_fused_gemm(one_chip, 128, 32768, 6144)
     assert "tpu_custom_call" in compiled.as_text()
 
 

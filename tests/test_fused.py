@@ -144,6 +144,38 @@ def test_fused_k_tiling_close():
     np.testing.assert_allclose(got, ref, atol=3e-5 * np.abs(ref).max())
 
 
+def _fused_sharded_one_device(x, pw, **blocks):
+    from jax.sharding import Mesh
+
+    mesh = Mesh(np.asarray(jax.devices()[:1]).reshape(1, 1),
+                ("data", "model"))
+    return ops.dsbp_matmul_fused_sharded(
+        x, pw, mesh, batch_axis=("data",), k_axis=None, n_axis="model",
+        **blocks)
+
+
+@pytest.mark.parametrize("entry", ["fused", "sharded"])
+@pytest.mark.parametrize("bk", [None, 128], ids=["one_k_block", "k_tiles"])
+@pytest.mark.parametrize("fmt", ["e4m3", "e5m2"])
+@pytest.mark.parametrize("preset", ["precise", "efficient"])
+def test_fused_output_tiles_reuse_aligned_input(preset, fmt, bk, entry):
+    """Three row blocks (the last one ragged) by four output tiles: the
+    first output tile of a row block aligns its input once per K tile, and
+    the other three read that back.  One K block stays bit-exact; K tiles
+    keep the tolerance of test_fused_k_tiling_close."""
+    cfg = _cfg(preset, fmt=fmt)
+    x = jnp.asarray(_data((20, 256), seed=24))
+    w = jnp.asarray(_data((256, 512), seed=25, spread=2))
+    pw = Q.pack_weights(w, cfg)
+    ref = np.asarray(Q.dsbp_matmul_ref(x, w, cfg))
+    call = ops.dsbp_matmul_fused if entry == "fused" else _fused_sharded_one_device
+    got = np.asarray(call(x, pw, bm=8, bn=128, bk=bk))
+    if bk is None:
+        np.testing.assert_array_equal(got, ref)
+    else:
+        np.testing.assert_allclose(got, ref, atol=3e-5 * np.abs(ref).max())
+
+
 # ---------------- no per-call weight relayout ----------------
 
 def test_fused_and_packed_make_zero_weight_relayouts():
