@@ -1,4 +1,5 @@
-"""Architecture configs: the 10 assigned archs + the paper's Llama-7b.
+"""Architecture configs: the 10 assigned archs, the paper's Llama-7b and
+Mellum2-12B-A2.5B.
 
 Each ``<arch>.py`` exports ``CONFIG`` (the exact published geometry) and the
 registry offers ``smoke_config()`` — a reduced same-family variant for CPU
@@ -49,6 +50,13 @@ class ArchConfig:
     # numerics / misc
     tie_embeddings: bool = False
     rope_theta: float = 10_000.0
+    # YaRN rotary scaling on the 'attn_full' layers (HF's rope_type
+    # "yarn"); yarn_factor 0 leaves every layer on the default rotary
+    yarn_factor: float = 0.0
+    yarn_original_max_len: int = 0
+    yarn_beta_fast: float = 32.0
+    yarn_beta_slow: float = 1.0
+    yarn_attention_factor: float = 1.0
     norm_eps: float = 1e-6
     dtype: str = "float32"  # activation/param compute dtype
     remat: bool = True
@@ -156,6 +164,7 @@ ARCH_IDS = [
     "recurrentgemma-2b",
     "mamba2-370m",
     "llama-7b-paper",
+    "mellum2-12b",
 ]
 
 _MODULES = {a: a.replace("-", "_") for a in ARCH_IDS}

@@ -208,11 +208,13 @@ def per_row_scale(x: jax.Array, fmt, margin: float = 1.0) -> jax.Array:
 
 
 @partial(jax.jit, static_argnames=("cfg",))
-def dsbp_quantize(x: jax.Array, cfg: DSBPConfig):
+def dsbp_quantize(x: jax.Array, cfg: DSBPConfig, tscale=None):
     """Full DSBP pipeline: f32 tensor -> aligned ints + scales + stats.
 
     The last axis of ``x`` is the reduction (MAC) axis and is grouped by
-    ``cfg.group_size``.  Returns a dict:
+    ``cfg.group_size``.  ``tscale`` (optional) is a power-of-two scale
+    taken over a larger tensor that ``x`` is part of, used in place of
+    ``x``'s own.  Returns a dict:
       a        int32 (..., n_g, G)  aligned mantissas (sign applied)
       scale    f32   (..., n_g)     group scales (power of two)
       bits     int32 (..., n_g)     predicted aligned-mantissa widths B_g
@@ -220,7 +222,9 @@ def dsbp_quantize(x: jax.Array, cfg: DSBPConfig):
       value    f32                  the FP8-quantized (pre-alignment) values
     """
     f = cfg.format
-    if cfg.scale_granularity == "row":
+    if tscale is not None:
+        tscale = jnp.asarray(tscale, jnp.float32)
+    elif cfg.scale_granularity == "row":
         tscale = per_row_scale(x, f)
     else:
         tscale = per_tensor_scale(x, f)

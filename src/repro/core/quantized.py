@@ -38,6 +38,7 @@ __all__ = [
     "grouped_int_matmul",
     "pack_weights",
     "packed_matmul",
+    "grouped_packed_matmul_ref",
     "dsbp_matmul_ref",
     "dsbp_matmul",
     "dsbp_matmul_ste",
@@ -178,6 +179,38 @@ def packed_matmul(x: jax.Array, pw: PackedDSBPWeight,
     qw = {"a": pw.a, "scale": pw.scale, "tscale": pw.tscale}
     y = grouped_int_matmul(qx, qw)
     return y.reshape(*batch_shape, pw.n)
+
+
+@partial(jax.jit, static_argnames=("bm", "input_cfg"))
+def grouped_packed_matmul_ref(x: jax.Array, ts: jax.Array,
+                              pw: PackedDSBPWeight, block_expert: jax.Array,
+                              bm: int, unit=None,
+                              input_cfg: DSBPConfig | None = None):
+    """Reference of the grouped expert GEMM (``kernels/dsbp_grouped.py``):
+    row block ``b`` of ``x (R, K)`` times the packed weights of expert
+    ``block_expert[b]`` of the stacked container ``pw (E, K, N)`` (or of
+    layer ``unit`` of a ``(U, E, K, N)`` stack), every row quantized under
+    the shared input tensor scale ``ts``.  -> (R, N) f32."""
+    icfg = input_cfg if input_cfg is not None else pw.cfg.input_cfg
+    r = x.shape[0]
+    xm = x.astype(jnp.float32)
+    if pw.padded_k != pw.k:
+        xm = jnp.pad(xm, ((0, 0), (0, pw.padded_k - pw.k)))
+    qx = dsbp.dsbp_quantize(xm, icfg, ts)
+    tw = jnp.asarray(pw.tscale)
+
+    def block(args):
+        b, e = args
+        rows = jax.lax.dynamic_slice_in_dim
+        qb = {"a": rows(qx["a"], b * bm, bm), "scale": rows(qx["scale"],
+                                                           b * bm, bm),
+              "tscale": qx["tscale"]}
+        at = (e,) if unit is None else (unit, e)
+        qw = {"a": pw.a[at], "scale": pw.scale[at], "tscale": tw[at]}
+        return grouped_int_matmul(qb, qw)
+
+    y = jax.lax.map(block, (jnp.arange(r // bm), block_expert))
+    return y.reshape(r, pw.n)
 
 
 @partial(jax.jit, static_argnames=("cfg",))

@@ -27,6 +27,7 @@ from repro.core.packed import PackedDSBPWeight
 from repro.core.quantized import QuantizedMatmulConfig, pack_weights
 
 from . import dsbp_fused as _df
+from . import dsbp_grouped as _dg
 from . import dsbp_matmul as _dm
 from . import fp8_quant_align as _qa
 from . import flash_attention as _fa
@@ -36,6 +37,7 @@ __all__ = [
     "dsbp_matmul_packed",
     "dsbp_matmul_fused",
     "dsbp_matmul_fused_sharded",
+    "dsbp_matmul_grouped",
     "dsbp_matmul_ste",
     "dsbp_matmul_fused_ste",
     "fp8_quant_align",
@@ -174,6 +176,54 @@ def dsbp_matmul_fused(
         bm=bm, bn=bn, bk=bk, interpret=interpret,
     )
     return y.reshape(*batch, pw.n)
+
+
+@partial(jax.jit, static_argnames=("bm", "input_cfg", "interpret", "bn"))
+def dsbp_matmul_grouped(
+    x: jax.Array,
+    pw: PackedDSBPWeight,
+    ts: jax.Array,
+    block_expert: jax.Array,
+    n_used: jax.Array,
+    bm: int,
+    unit=None,
+    input_cfg: DSBPConfig | None = None,
+    interpret: bool | None = None,
+    bn: int = 256,
+):
+    """Grouped one-pass DSBP GEMM of a mixture-of-experts layer:
+    ``x (R, K)`` rows sorted by expert, row block ``b`` (``bm`` rows)
+    against expert ``block_expert[b]`` of the stacked container
+    ``pw (E, K, N)`` — or, with ``unit``, of layer ``unit`` of a
+    ``(U, E, K, N)`` stack, read in place — blocks past ``n_used``
+    skipped -> (R, N) f32.
+
+    ``ts`` is the power-of-two input tensor scale, taken by the caller
+    over the tensor the rows were gathered from, so grouping changes no
+    row's quantization.  The weight operands are the container's stored
+    kernel-layout arrays (no relayout); each row's result is bit-exact vs
+    ``dsbp_matmul_ref`` of the row against its expert's weights under that
+    scale (``kernels/dsbp_grouped.py``)."""
+    lead = pw.ka.ndim - 2
+    if lead != (1 if unit is None else 2):
+        raise ValueError(f"dsbp_matmul_grouped needs an (E, K, N) container, "
+                         f"or a (U, E, K, N) stack with a unit; got ka "
+                         f"{pw.ka.shape}")
+    if x.shape[-1] != pw.k:
+        raise ValueError(f"activation K={x.shape[-1]} != packed logical "
+                         f"K={pw.k}")
+    icfg = input_cfg if input_cfg is not None else pw.cfg.input_cfg
+    xm = x.astype(jnp.float32)
+    if pw.padded_k != pw.k:  # mirror the zero lanes the weights packed with
+        xm = jnp.pad(xm, ((0, 0), (0, pw.padded_k - pw.k)))
+    stack = pw.ka.shape[:-2] if unit is not None else (1, *pw.ka.shape[:-2])
+    tsw = jnp.asarray(pw.tscale, jnp.float32)
+    tw = jnp.broadcast_to(tsw.reshape(*stack, 1, -1), (*stack, 1, pw.n))
+    return _dg.dsbp_grouped_kernel_call(
+        xm, ts, pw.ka.reshape(*stack, *pw.ka.shape[-2:]),
+        pw.kscale.reshape(*stack, *pw.kscale.shape[-2:]), tw, block_expert,
+        n_used, 0 if unit is None else unit, icfg, bm=bm, bn=bn,
+        interpret=interpret)
 
 
 def dsbp_matmul_fused_sharded(

@@ -15,7 +15,8 @@ from . import recurrent as rec
 from . import ssd as ssd_mod
 from .attention import (blockwise_attention, decode_attention, gather_kv_view,
                         pv_out, qk_logits, verify_attention)
-from .layers import Quant, dense, init_dense, init_norm, rms_norm, rope
+from .layers import (Quant, dense, init_dense, init_norm, rms_norm, rope,
+                     rope_scaling)
 
 __all__ = [
     "init_layer",
@@ -97,24 +98,26 @@ def _ffn(params, x, quant):
         return dense(params["w2"], h, quant, name="w2")
 
 
-def _mlp_part(params, x, cfg, quant, no_drop=False):
+def _mlp_part(params, x, cfg, quant, no_drop=False, expert_rows=None):
     y = rms_norm(params["norm2"], x, cfg.norm_eps)
     if cfg.n_experts:
-        return x + moe_mod.moe_ffn(params["moe"], y, cfg, quant, no_drop)
+        return x + moe_mod.moe_ffn(params["moe"], y, cfg, quant, no_drop,
+                                   expert_rows=expert_rows)
     return x + _ffn(params["ffn"], y, quant)
 
 
 # ---------------- attention, sequence mode ----------------
 
 @jax.named_scope("qkv")
-def _qkv(params, y, cfg, quant, positions):
+def _qkv(params, y, cfg, quant, positions, kind):
     b, s, _ = y.shape
     dh = cfg.d_head
     q = dense(params["wq"], y, quant, name="wq").reshape(b, s, cfg.n_heads, dh)
     k = dense(params["wk"], y, quant, name="wk").reshape(b, s, cfg.n_kv_heads, dh)
     v = dense(params["wv"], y, quant, name="wv").reshape(b, s, cfg.n_kv_heads, dh)
-    q = rope(q.transpose(0, 2, 1, 3), positions, cfg.rope_theta)
-    k = rope(k.transpose(0, 2, 1, 3), positions, cfg.rope_theta)
+    yarn = rope_scaling(cfg, kind)
+    q = rope(q.transpose(0, 2, 1, 3), positions, cfg.rope_theta, yarn)
+    k = rope(k.transpose(0, 2, 1, 3), positions, cfg.rope_theta, yarn)
     return q, k, v.transpose(0, 2, 1, 3)
 
 
@@ -128,7 +131,7 @@ def _attn_out(params, o, x, cfg, quant):
 
 def _attn_seq(params, x, cfg, kind, quant, positions, lengths=None):
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
-    q, k, v = _qkv(params["attn"], y, cfg, quant, positions)
+    q, k, v = _qkv(params["attn"], y, cfg, quant, positions, kind)
     window = cfg.window if kind == "attn_local" else 0
     o = blockwise_attention(q, k, v, causal=True, window=window, kv_lens=lengths)
     x = _attn_out(params["attn"], o, x, cfg, quant)
@@ -359,7 +362,7 @@ def _attn_decode(params, x, cfg, kind, quant, cache, pos):
     b = x.shape[0]
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
-    q, k, v = _qkv(params["attn"], y, cfg, quant, posb[:, None])
+    q, k, v = _qkv(params["attn"], y, cfg, quant, posb[:, None], kind)
     s_c = cache["k"].shape[2]
     slot = posb % s_c  # (B,) per-slot ring position
     bidx = jnp.arange(b)
@@ -410,7 +413,7 @@ def _attn_decode_paged(params, x, cfg, kind, quant, pool, table, posb,
     (idle, or mid-chunked-prefill during a decode step) write nothing and
     their output is discarded by the engine."""
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
-    q, k, v = _qkv(params["attn"], y, cfg, quant, posb[:, None])
+    q, k, v = _qkv(params["attn"], y, cfg, quant, posb[:, None], kind)
     pool = write_kv_blocks(pool, table, k, v, posb, write_len, s_c)
     ck = _gather_kv_entry(pool["k"], table, s_c)
     cv = _gather_kv_entry(pool["v"], table, s_c)
@@ -433,7 +436,7 @@ def _attn_verify(params, x, cfg, kind, quant, cache, posb):
     b, t, _ = x.shape
     positions = posb[:, None] + jnp.arange(t)[None, :]  # (B, T)
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
-    q, k, v = _qkv(params["attn"], y, cfg, quant, positions)
+    q, k, v = _qkv(params["attn"], y, cfg, quant, positions, kind)
     window = cfg.window if kind == "attn_local" else 0
     # quantize-first: the fresh K/V attend in their CACHED representation,
     # so a T-token verify equals T chained decode steps token for token
@@ -497,7 +500,7 @@ def _attn_verify_paged(params, x, cfg, kind, quant, pool, table, posb,
     t = x.shape[1]
     positions = posb[:, None] + jnp.arange(t)[None, :]  # (B, T)
     y = rms_norm(params["norm1"], x, cfg.norm_eps)
-    q, k, v = _qkv(params["attn"], y, cfg, quant, positions)
+    q, k, v = _qkv(params["attn"], y, cfg, quant, positions, kind)
     window = cfg.window if kind == "attn_local" else 0
     ck = _gather_kv_entry(pool["k"], table, s_c)
     cv = _gather_kv_entry(pool["v"], table, s_c)
@@ -512,13 +515,14 @@ def _attn_verify_paged(params, x, cfg, kind, quant, pool, table, posb,
 
 
 def layer_verify_paged(params, x, cfg, kind, cache, table, pos, quant=None,
-                       s_c: int = 0):
+                       s_c: int = 0, expert_rows=None):
     """T tokens through one layer in paged verify mode (spec verify AND
     chunked prefill ride this path).  Unlike dense :func:`layer_verify`
     nothing is committed here: returns (x, steps) where ``steps`` holds the
     fresh per-layer K/V (attention) or per-step recurrent states, and
     :func:`rollback_kv_cache_paged` / :func:`select_state_step` commit the
-    accepted prefix (``keep`` 0 freezes a lane entirely)."""
+    accepted prefix (``keep`` 0 freezes a lane entirely).  A grouped MoE
+    layer appends its per-expert row counts to ``expert_rows``."""
     b = x.shape[0]
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     params = gather_unit_params(params)
@@ -526,7 +530,8 @@ def layer_verify_paged(params, x, cfg, kind, cache, table, pos, quant=None,
     if kind in ("attn_full", "attn_local"):
         x, steps = _attn_verify_paged(params, x, cfg, kind, quant, cache,
                                       table, posb, s_c)
-        x = _mlp_part(params, x, cfg, quant, no_drop=True)
+        x = _mlp_part(params, x, cfg, quant, no_drop=True,
+                      expert_rows=expert_rows)
         return x, steps
     if kind == "rglru":
         y = rms_norm(params["norm1"], x, cfg.norm_eps)
@@ -630,12 +635,13 @@ def layer_decode(params, x, cfg, kind, cache, pos, quant=None):
 
 
 def layer_decode_paged(params, x, cfg, kind, cache, table, pos, write_len,
-                       quant=None, s_c: int = 0):
+                       quant=None, s_c: int = 0, expert_rows=None):
     """One paged decode step.  ``cache`` is the layer's pooled {'k','v'}
     (attention kinds, block axis leading) or its dense per-lane state
     (recurrent kinds, frozen via :func:`freeze_state` when
     ``write_len[b]`` is 0).  Returns (x, new_cache); active lanes
-    (``write_len`` 1) are bit-identical to :func:`layer_decode`."""
+    (``write_len`` 1) are bit-identical to :func:`layer_decode`.  A grouped
+    MoE layer appends its per-expert row counts to ``expert_rows``."""
     b = x.shape[0]
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (b,))
     params = gather_unit_params(params)
@@ -643,7 +649,8 @@ def layer_decode_paged(params, x, cfg, kind, cache, table, pos, write_len,
     if kind in ("attn_full", "attn_local"):
         x, cache = _attn_decode_paged(params, x, cfg, kind, quant, cache,
                                       table, posb, write_len, s_c)
-        x = _mlp_part(params, x, cfg, quant, no_drop=True)
+        x = _mlp_part(params, x, cfg, quant, no_drop=True,
+                      expert_rows=expert_rows)
         return x, cache
     if kind == "rglru":
         y = rms_norm(params["norm1"], x, cfg.norm_eps)

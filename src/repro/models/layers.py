@@ -11,13 +11,17 @@ part of the distribution contract:
 """
 from __future__ import annotations
 
+import math
+from typing import NamedTuple
+
 import jax
 import jax.numpy as jnp
 
 from repro.core.packed import PackedDSBPWeight, get_quant_method
 from repro.core.quantized import PRESETS
 
-__all__ = ["rms_norm", "dense", "init_dense", "rope", "init_norm", "Quant"]
+__all__ = ["rms_norm", "dense", "init_dense", "rope", "rope_scaling",
+           "yarn_inv_freq", "Yarn", "init_norm", "Quant"]
 
 
 def init_norm(d: int, dtype) -> dict:
@@ -112,17 +116,68 @@ def dense(w, x: jax.Array, quant: Quant | None = None,
     return jnp.einsum("...k,kn->...n", x, w)
 
 
-def _rope_angles(positions: jax.Array, d_head: int, theta: float):
+class Yarn(NamedTuple):
+    """YaRN rotary scaling (HF ``rope_type`` "yarn")."""
+
+    factor: float
+    original_max_len: int
+    beta_fast: float
+    beta_slow: float
+    attention_factor: float
+
+
+def rope_scaling(cfg, kind: str) -> Yarn | None:
+    """The rotary scaling a layer of ``kind`` runs under: YaRN on
+    'attn_full' layers of a config that sets ``yarn_factor``, else none."""
+    if kind != "attn_full" or not cfg.yarn_factor:
+        return None
+    return Yarn(cfg.yarn_factor, cfg.yarn_original_max_len,
+                cfg.yarn_beta_fast, cfg.yarn_beta_slow,
+                cfg.yarn_attention_factor)
+
+
+def yarn_inv_freq(d_head: int, theta: float, yarn: Yarn) -> jax.Array:
+    """Inverse frequencies (d_head/2,) as HF's ``_compute_yarn_parameters``
+    makes them: dimensions that turn fewer than ``beta_slow`` times over
+    the original context are interpolated (divided by ``factor``), those
+    that turn more than ``beta_fast`` times keep their frequency, and a
+    linear ramp blends the band between."""
     half = d_head // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    inv_extrap = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+
+    def turns_dim(beta):
+        return (d_head * math.log(yarn.original_max_len / (beta * 2 * math.pi))
+                / (2 * math.log(theta)))
+
+    low = max(math.floor(turns_dim(yarn.beta_fast)), 0)
+    high = min(math.ceil(turns_dim(yarn.beta_slow)), d_head - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(half, dtype=jnp.float32) - low) / (high - low),
+                    0.0, 1.0)
+    return inv_extrap / yarn.factor * ramp + inv_extrap * (1.0 - ramp)
+
+
+def _rope_angles(positions: jax.Array, d_head: int, theta: float,
+                 yarn: Yarn | None = None):
+    if yarn is None:
+        half = d_head // 2
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    else:
+        freqs = yarn_inv_freq(d_head, theta, yarn)
     ang = positions[..., None].astype(jnp.float32) * freqs  # (..., S, half)
-    return jnp.cos(ang), jnp.sin(ang)
+    if yarn is None:
+        return jnp.cos(ang), jnp.sin(ang)
+    scale = jnp.float32(yarn.attention_factor)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
-def rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0) -> jax.Array:
-    """Rotary embedding. x: (B, H, S, D), positions: (B, S) or (S,)."""
+def rope(x: jax.Array, positions: jax.Array, theta: float = 10_000.0,
+         yarn: Yarn | None = None) -> jax.Array:
+    """Rotary embedding. x: (B, H, S, D), positions: (B, S) or (S,);
+    ``yarn`` scales the frequencies and the magnitudes (:class:`Yarn`)."""
     d = x.shape[-1]
-    cos, sin = _rope_angles(positions, d, theta)  # (B, S, D/2) or (S, D/2)
+    cos, sin = _rope_angles(positions, d, theta, yarn)  # (B, S, D/2) or (S, D/2)
     if cos.ndim == 2:  # (S, half) -> broadcast over batch
         cos, sin = cos[None], sin[None]
     cos, sin = cos[:, None], sin[:, None]  # add head axis

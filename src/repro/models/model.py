@@ -22,6 +22,7 @@ from repro.configs import ArchConfig
 from repro.kvq import kv_policy_cfg
 
 from . import blocks
+from . import moe as MOE
 from .attention import HIGHEST
 from .layers import Quant, init_norm, rms_norm
 
@@ -437,7 +438,8 @@ def decode_step(params, token_batch: dict, cache, pos, cfg: ArchConfig):
 
 
 def decode_step_paged(params, token_batch: dict, cache, table, pos, write_len,
-                      cfg: ArchConfig, max_len: int):
+                      cfg: ArchConfig, max_len: int,
+                      expert_rows: bool = False):
     """One token per lane through the paged cached stack.  Mirrors
     :func:`decode_step` with the KV write/read going through ``table``
     ((B, MB) int32): KV pool leaves have no batch axis, so the unit scan
@@ -446,35 +448,78 @@ def decode_step_paged(params, token_batch: dict, cache, table, pos, write_len,
     (bit-identical to dense), 0 freezes KV and recurrent state (idle lanes
     and chunk-phase lanes mid-prefill).  ``max_len`` is static (it fixes
     each layer's logical ring length S_c, which dense reads off the cache
-    shape).  Returns (logits (B, 1, V), new_cache)."""
+    shape).  Returns (logits (B, 1, V), new_cache), and with
+    ``expert_rows`` a third output: :func:`_layer_rows`."""
     quant = Quant(cfg.quant, cfg.quant_method)
     x = _embed_step(params, token_batch, cfg)
+    units, held = MOE.hold_experts(params["units"])
 
     def unit_body(carry, stacked):
         xc = carry
-        p_stack, c_stack = stacked
-        new_caches = []
+        p_stack, c_stack, u = _unit_xs(stacked)
+        new_caches, rows = [], []
         for i, kind in enumerate(cfg.pattern):
             xc, nc = blocks.layer_decode_paged(
-                {k: v for k, v in p_stack[i].items()}, xc, cfg, kind,
-                c_stack[i], table, pos, write_len, quant,
-                s_c=blocks.cache_len(cfg, kind, max_len),
+                MOE.with_experts(dict(p_stack[i]), held[i], u), xc, cfg,
+                kind, c_stack[i], table, pos, write_len, quant,
+                s_c=blocks.cache_len(cfg, kind, max_len), expert_rows=rows,
             )
             new_caches.append(nc)
-        return xc, tuple(new_caches)
+        out = tuple(new_caches)
+        return xc, ((out, _rows_of(rows, cfg, len(cfg.pattern)))
+                    if expert_rows else out)
 
-    x, new_unit_caches = _scan_layers(
-        unit_body, x, (tuple(params["units"]), tuple(cache["units"])), cfg)
-    new_tail = []
+    x, unit_out = _scan_layers(
+        unit_body, x, _scan_xs(units, cache["units"], held, cfg), cfg)
+    new_unit_caches, unit_rows = unit_out if expert_rows else (unit_out, None)
+    new_tail, tail_rows = [], []
     for i, kind in enumerate(cfg.tail):
         x, nc = blocks.layer_decode_paged(
             params["tail"][i], x, cfg, kind, cache["tail"][i], table, pos,
             write_len, quant, s_c=blocks.cache_len(cfg, kind, max_len),
+            expert_rows=tail_rows,
         )
         new_tail.append(nc)
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = _head(params, x, cfg)
-    return logits, {"units": list(new_unit_caches), "tail": new_tail}
+    new_cache = {"units": list(new_unit_caches), "tail": new_tail}
+    if expert_rows:
+        return logits, new_cache, _layer_rows(unit_rows, tail_rows, cfg)
+    return logits, new_cache
+
+
+def _scan_xs(units, cache_units, held, cfg: ArchConfig):
+    """What a paged step scans: the unit params and caches, and the unit
+    index where expert stacks are held out of the scan
+    (:func:`repro.models.moe.hold_experts`)."""
+    xs = (tuple(units), tuple(cache_units))
+    if any(h is not None for h in held):
+        xs += (jnp.arange(cfg.n_units, dtype=jnp.int32),)
+    return xs
+
+
+def _unit_xs(stacked):
+    """(unit params, unit caches, unit index or None) of one scan step."""
+    return (*stacked, None) if len(stacked) == 2 else stacked
+
+
+def _rows_of(rows, cfg: ArchConfig, n_layers: int):
+    """Layers' per-expert row counts as (n_layers, E) int32; zeros where
+    no layer ran the grouped expert path."""
+    if rows:
+        return jnp.stack(rows)
+    return jnp.zeros((n_layers, cfg.n_experts), jnp.int32)
+
+
+def _layer_rows(unit_rows, tail_rows, cfg: ArchConfig):
+    """(n_layers, E) int32 routed rows per layer and expert, in stack
+    order (unit u's pattern position i is layer ``u * len(pattern) + i``,
+    the tail after), from the grouped expert path
+    (``moe_ffn``'s ``expert_rows``)."""
+    parts = [unit_rows.reshape(-1, cfg.n_experts)]
+    if cfg.tail:
+        parts.append(_rows_of(tail_rows, cfg, len(cfg.tail)))
+    return jnp.concatenate(parts)
 
 
 # ---------------- speculative verification (DESIGN.md §10) ----------------
@@ -567,43 +612,53 @@ def rollback_cache(old_cache, new_cache, rollback, keep, pos,
 
 
 def verify_step_paged(params, token_batch: dict, cache, table, pos,
-                      cfg: ArchConfig, max_len: int):
+                      cfg: ArchConfig, max_len: int,
+                      expert_rows: bool = False):
     """Paged multi-token step with DEFERRED commit — spec verification AND
     chunked prefill ride this one path.  Same logits contract as
     :func:`verify_step` (T chained decode steps), but NOTHING is written:
     returns (logits, steps) where ``steps`` mirrors the cache tree with the
     fresh per-layer K/V ((R,) B, H, T, D) for KV kinds and per-step
     recurrent states for the rest; :func:`rollback_cache_paged` commits the
-    accepted prefix per lane (``keep[b]`` in [0, T], 0 = frozen lane)."""
+    accepted prefix per lane (``keep[b]`` in [0, T], 0 = frozen lane).
+    With ``expert_rows`` a third output: :func:`_layer_rows`."""
     quant = Quant(cfg.quant, cfg.quant_method)
     x = _embed_step(params, token_batch, cfg)
     posb = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (x.shape[0],))
+    units, held = MOE.hold_experts(params["units"])
 
     def unit_body(carry, stacked):
         xc = carry
-        p_stack, c_stack = stacked
-        steps = []
+        p_stack, c_stack, u = _unit_xs(stacked)
+        steps, rows = [], []
         for i, kind in enumerate(cfg.pattern):
             xc, st = blocks.layer_verify_paged(
-                {k: v for k, v in p_stack[i].items()}, xc, cfg, kind,
-                c_stack[i], table, posb, quant,
-                s_c=blocks.cache_len(cfg, kind, max_len),
+                MOE.with_experts(dict(p_stack[i]), held[i], u), xc, cfg,
+                kind, c_stack[i], table, posb, quant,
+                s_c=blocks.cache_len(cfg, kind, max_len), expert_rows=rows,
             )
             steps.append(st)
-        return xc, tuple(steps)
+        out = tuple(steps)
+        return xc, ((out, _rows_of(rows, cfg, len(cfg.pattern)))
+                    if expert_rows else out)
 
-    x, unit_steps = _scan_layers(
-        unit_body, x, (tuple(params["units"]), tuple(cache["units"])), cfg)
-    tail_steps = []
+    x, unit_out = _scan_layers(
+        unit_body, x, _scan_xs(units, cache["units"], held, cfg), cfg)
+    unit_steps, unit_rows = unit_out if expert_rows else (unit_out, None)
+    tail_steps, tail_rows = [], []
     for i, kind in enumerate(cfg.tail):
         x, st = blocks.layer_verify_paged(
             params["tail"][i], x, cfg, kind, cache["tail"][i], table, posb,
             quant, s_c=blocks.cache_len(cfg, kind, max_len),
+            expert_rows=tail_rows,
         )
         tail_steps.append(st)
     x = rms_norm(params["final_norm"], x, cfg.norm_eps)
     logits = _head(params, x, cfg)
-    return logits, {"units": list(unit_steps), "tail": tail_steps}
+    steps = {"units": list(unit_steps), "tail": tail_steps}
+    if expert_rows:
+        return logits, steps, _layer_rows(unit_rows, tail_rows, cfg)
+    return logits, steps
 
 
 @jax.named_scope("kv_write")

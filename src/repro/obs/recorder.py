@@ -166,6 +166,20 @@ class ServeRecorder:
         self.metrics.counter("serve_kv_bytes_gathered_total").inc(
             gathered_rows * row_bytes)
 
+    def expert_rows(self, step, kind, expert_rows_real, expert_rows_padded,
+                    experts_touched, expert_rows_max) -> None:
+        """One paged pass's grouped expert work (``kind`` 'decode' or
+        'chunk'): (token, expert) rows routed, padding rows added to fill
+        each expert's row blocks, (layer, expert) pairs with rows, and the
+        most rows one expert of one layer took."""
+        if not self.enabled:
+            return
+        self.metrics.counter("serve_expert_rows_total").inc(expert_rows_real)
+        self.metrics.counter("serve_expert_pad_rows_total").inc(
+            expert_rows_padded)
+        self.metrics.counter("serve_experts_touched_total").inc(
+            experts_touched)
+
     def spec_round(self, step, keeps) -> None:
         if not self.enabled:
             return

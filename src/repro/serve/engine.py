@@ -42,6 +42,7 @@ from repro.core.packed import (PackedDSBPWeight, key_entry_str,
 from repro.core.quantized import PRESETS, pack_weights
 from repro.kvq import is_kv_leaf_path, kv_cache_nbytes, tree_has_packed_kv
 from repro.models import model as M
+from repro.models import moe as MOE
 from repro.obs import ServeRecorder, span
 
 __all__ = ["ServeConfig", "Request", "Engine", "pack_weights_int8",
@@ -225,6 +226,10 @@ class _ServeControl:
     step: int = 0                # scheduler iteration counter
     admit_step: dict = dataclasses.field(default_factory=dict)
     preempts: dict = dataclasses.field(default_factory=dict)
+    # MoE: passes whose per-expert row counts the host has not read yet
+    # ((kind, step, rows, row block)), and the latest read per pass kind
+    expert_pending: list = dataclasses.field(default_factory=list)
+    expert_last: dict = dataclasses.field(default_factory=dict)
 
 
 def pack_weights_int8(params, preset="precise", mesh=None):
@@ -296,7 +301,26 @@ def pack_tree(params, preset="precise", mesh=None, prefix=()):
         return (pack_weights_sharded(leaf, cfg, mesh) if mesh is not None
                 else pack_weights(leaf, cfg))
 
-    return jax.tree_util.tree_map_with_path(pack, params)
+    packed = jax.tree_util.tree_map_with_path(pack, params)
+    # one device: MoE gate and up experts become one (E, d, 2f) container,
+    # the grouped serving GEMM's operand (models/moe.py); a mesh keeps the
+    # two for the sharding rules, which bind to their names
+    return _fuse_expert_gate_up(packed) if mesh is None else packed
+
+
+def _fuse_expert_gate_up(tree):
+    from repro.models.moe import fuse_gate_up
+
+    if isinstance(tree, list):
+        return [_fuse_expert_gate_up(t) for t in tree]
+    if not isinstance(tree, dict):
+        return tree
+    w1, w3 = tree.get("w1"), tree.get("w3")
+    if ("router" in tree and isinstance(w1, PackedDSBPWeight)
+            and isinstance(w3, PackedDSBPWeight) and w1.cfg == w3.cfg):
+        rest = {k: v for k, v in tree.items() if k not in ("w1", "w3")}
+        return {**rest, "w13": fuse_gate_up(w1, w3)}
+    return {k: _fuse_expert_gate_up(v) for k, v in tree.items()}
 
 
 def _pack_stats(packed) -> dict:
@@ -581,16 +605,19 @@ class Engine:
         self._chunk_T = min(scfg.prefill_bucket,
                             *(self._kv_scs or [scfg.prefill_bucket]))
         cfg_, max_len = cfg, scfg.max_len
+        # MoE steps also return their per-layer, per-expert routed rows
+        xr = self._expert_rows = bool(cfg.n_experts)
 
         def _decode_paged_fn(p, tok, cache, table, pos, write_len):
             with self._trace_ctx():
                 return M.decode_step_paged(p, tok, cache, table, pos,
-                                           write_len, cfg_, max_len)
+                                           write_len, cfg_, max_len,
+                                           expert_rows=xr)
 
         def _verify_paged_fn(p, tok, cache, table, pos):
             with self._trace_ctx():
                 return M.verify_step_paged(p, tok, cache, table, pos, cfg_,
-                                           max_len)
+                                           max_len, expert_rows=xr)
 
         def _commit_paged_fn(cache, table, steps, keep, pos):
             with self._trace_ctx():
@@ -1362,6 +1389,10 @@ class Engine:
                  # table, per KV layer) and the rows decoding lanes held
                  "kv_rows_gathered": 0, "kv_rows_live": 0,
                  **self._robust_stats()}
+        if self._expert_rows:
+            stats.update(expert_passes=0, expert_rows_real=0,
+                         expert_rows_padded=0, experts_touched=0,
+                         expert_rows_max=0)
         ctl = _ServeControl(stats=stats, out={},
                             status={r.uid: "queued" for r in queue},
                             faults=faults)
@@ -1445,7 +1476,8 @@ class Engine:
                         self.obs.decode_step(ctl.step, len(dec) + len(chk),
                                              dt)
                     if chk:
-                        with span("serve.chunk"):
+                        with span("serve.chunk",
+                                  **ctl.expert_last.get("chunk", {})):
                             cache, rng = self._chunk_step(
                                 cache, lanes, tables, alloc, prefix, queue,
                                 chk, tok, pos, ctl, rng)
@@ -1453,6 +1485,7 @@ class Engine:
                         FA.check_invariants(alloc, tables, lanes, prefix)
                         stats["invariant_checks"] += 1
                     ctl.step += 1
+            self._expert_flush(ctl)
             completed = True
         finally:
             # conservation on ANY exit: every live lane's block references
@@ -1816,9 +1849,10 @@ class Engine:
             if lanes[i] is None:
                 keep[i] = 0
         fin = [(i, n) for i, n in fin if lanes[i] is not None]
-        logits, steps = self._verify_paged(
+        logits, steps, *rows = self._verify_paged(
             self.params, {"tokens": jnp.asarray(toks)}, cache,
             jnp.asarray(tables), jnp.asarray(posv))
+        self._expert_pass(ctl, "chunk", rows, B * T, T)
         cache = self._commit_paged(cache, jnp.asarray(tables), steps,
                                    jnp.asarray(keep), jnp.asarray(posv))
         stats["chunk_steps"] += 1
@@ -1832,6 +1866,7 @@ class Engine:
             first, rng = self._sample_next(jnp.asarray(sel), rng)
             with span("serve.chunk_wait"):
                 first = np.asarray(first)
+            self._expert_flush(ctl)
             badset = set(badrows)
             for j, (i, _) in enumerate(fin):
                 r = lanes[i]["req"]
@@ -1877,12 +1912,14 @@ class Engine:
         stats["kv_rows_live"] += live_rows
         self.obs.kv_read(ctl.step, gathered, live_rows, kv_row_bytes)
         with span("serve.decode", kv_rows_gathered=gathered,
-                  kv_rows_live=live_rows):
+                  kv_rows_live=live_rows,
+                  **ctl.expert_last.get("decode", {})):
             step_toks = {"tokens": jnp.asarray(tok)[:, None]}
             prev = cache if self._guard == "fallback" else None
-            logits, cache = self._decode_paged(
+            logits, cache, *rows = self._decode_paged(
                 self.params, step_toks, cache, jnp.asarray(tables),
                 jnp.asarray(pos), jnp.asarray(live_m))
+            self._expert_pass(ctl, "decode", rows, self.lanes, 1)
             last, bad = self._apply_guard(
                 logits[:, -1], dec, lambda i: lanes[i]["req"].uid, ctl,
                 cache=cache)
@@ -1902,6 +1939,7 @@ class Engine:
             nxt, rng = self._sample_next(jnp.asarray(last), rng)
         with span("serve.wait"):
             nxt = np.asarray(nxt)
+        self._expert_flush(ctl)
         with span("serve.tokens"):
             for i in dec:
                 if lanes[i] is None:
@@ -1981,6 +2019,38 @@ class Engine:
             pos[i] += committed
             tok[i] = int(target[i, committed - 1])
         return cache
+
+    def _expert_pass(self, ctl: _ServeControl, kind: str, rows, tokens: int,
+                     seq: int) -> None:
+        """Queue a paged pass's (layers, E) routed-row counts (``rows`` is
+        the step's extra output, empty for a model without experts); the
+        host reads them at its next sync, with the tokens it fetches."""
+        if rows:
+            bm = MOE.block_rows(tokens, seq, self.cfg.top_k)
+            ctl.expert_pending.append((kind, ctl.step, rows[0], bm))
+
+    def _expert_flush(self, ctl: _ServeControl) -> None:
+        """Read the queued passes' expert row counts into the serve stats
+        and the ``expert_rows`` hook: rows routed, padding rows added to
+        reach each expert's row block, (layer, expert) pairs touched and
+        the most rows one expert took.  The next ``serve.decode`` /
+        ``serve.chunk`` span carries the latest pass of its kind."""
+        stats = ctl.stats
+        for kind, step, rows, bm in ctl.expert_pending:
+            c = np.asarray(rows).astype(np.int64)
+            got = {"expert_rows_real": int(c.sum()),
+                   "expert_rows_padded": int((-(-c // bm) * bm - c).sum()),
+                   "experts_touched": int((c > 0).sum()),
+                   "expert_rows_max": int(c.max(initial=0))}
+            for key in ("expert_rows_real", "expert_rows_padded",
+                        "experts_touched"):
+                stats[key] += got[key]
+            stats["expert_rows_max"] = max(stats["expert_rows_max"],
+                                           got["expert_rows_max"])
+            stats["expert_passes"] += 1
+            ctl.expert_last[kind] = got
+            self.obs.expert_rows(step, kind, **got)
+        ctl.expert_pending.clear()
 
     def _done(self, t: int, emitted: list, r: Request) -> bool:
         eos = self.scfg.eos_id

@@ -102,3 +102,31 @@ def test_yi9b_decode_layer_compiles_for_v5e(one_chip, monkeypatch):
     compiled = jax.jit(lambda p, x, c, pos: blocks.layer_decode(
         p, x, cfg, "attn_full", c, pos, quant)).lower(*args).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# Mellum2's expert GEMMs (K, N): gate and up side by side, then down, at
+# a decode pass's rows (32 tokens x top-8, row block 8) and a chunk
+# pass's (512 x 8, row block 128), over a 4-layer stack read in place
+MELLUM_KN = [(2304, 1792), (896, 2304)]
+
+
+@pytest.mark.parametrize("rows,bm", [(704, 8), (12288, 128)])
+@pytest.mark.parametrize("k,n", MELLUM_KN)
+def test_grouped_gemm_compiles_for_v5e(one_chip, rows, bm, k, n):
+    from repro.kernels.dsbp_grouped import dsbp_grouped_kernel_call
+
+    cfg = PRESETS["precise"].input_cfg
+    args = _abstract([
+        jax.ShapeDtypeStruct((rows, k), jnp.float32),            # x
+        jax.ShapeDtypeStruct((1, 1), jnp.float32),               # ts
+        jax.ShapeDtypeStruct((4, 64, k, n), jnp.int8),           # ka
+        jax.ShapeDtypeStruct((4, 64, k // 64, n), jnp.float32),  # kscale
+        jax.ShapeDtypeStruct((4, 64, 1, n), jnp.float32),        # tscale
+        jax.ShapeDtypeStruct((rows // bm,), jnp.int32),          # table
+        jax.ShapeDtypeStruct((1,), jnp.int32),                   # used
+        jax.ShapeDtypeStruct((1,), jnp.int32),                   # layer
+    ], one_chip)
+    compiled = jax.jit(lambda *a: dsbp_grouped_kernel_call(
+        *a, cfg, bm=bm, interpret=False)).lower(*args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text and "dsbp_grouped_kernel_call" in text
